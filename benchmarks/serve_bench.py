@@ -309,7 +309,7 @@ def _zero1_compress() -> dict:
     drift = max(float(jnp.max(jnp.abs(pf[k] - pc[k]))) for k in params)
     return {
         "steps": ZERO1_STEPS,
-        "padded": spec.padded,
+        "padded": spec.size,
         "bytes_fp32": bytes_f,
         "bytes_int8": bytes_c,
         "byte_ratio": bytes_c / bytes_f,
@@ -452,6 +452,8 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--quick", action="store_true",
                     help="shorter traces / fewer decode tokens")

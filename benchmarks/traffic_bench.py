@@ -589,6 +589,8 @@ def run(quick: bool = False, reuse_only: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--reuse", action="store_true",

@@ -11,7 +11,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro import tiering as tm
+
+enable_compile_cache()
 
 VOCAB = 256_000
 ROWS = tm.EMBED_ROWS_PER_PAGE

@@ -13,8 +13,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.tiering import (DaemonParams, NeoMemDaemon, ResourceSpec,
                            StreamResource)
+
+enable_compile_cache()
 
 N_PAGES, N_SLOTS = 8192, 1024
 spec = ResourceSpec(name="demo", n_pages=N_PAGES, hot_slots=N_SLOTS,

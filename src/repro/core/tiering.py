@@ -2,8 +2,7 @@
 
 The TPU-native analogue of the paper's fast (DRAM) / slow (CXL) NUMA pair:
 a fixed pool of fast-tier *slots* (HBM-resident cache buffers) in front of a
-slow-tier *backing store* (host memory on real TPU; a logically separate
-array on the CPU backend — see DESIGN.md §7).
+slow-tier *backing store* (pinned host memory — see DESIGN.md §7).
 
 Faithful pieces:
   * promotion of NeoProf-reported hot pages, bounded by the migration quota;

@@ -28,10 +28,9 @@ its JAX/XLA equivalent:
 
   host_offload.py  The DRAM/CXL tier pair itself.  ``to_fast_tier`` /
                    ``to_slow_tier`` place arrays by JAX ``memory_kind``
-                   (device HBM = fast, pinned host = slow) and degrade to
-                   logical separation on backends without memory-kind
-                   support (CPU), mirroring the paper's fallback to
-                   software-managed tiering.
+                   (device HBM = fast, pinned host = slow);
+                   ``host_take`` / ``host_put`` gather and scatter a
+                   host store inside a jit, moving only the named rows.
 """
 from repro.dist import compression, host_offload, pipeline, sharding
 

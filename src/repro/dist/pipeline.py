@@ -12,11 +12,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # newer jax
-    from jax import shard_map  # type: ignore
-
 
 def pipeline_apply(stage_fn, stage_params, x, *, mesh, axis: str):
     """Apply ``stage_fn(w_i, .)`` for i = 0..n-1 as a microbatch pipeline.
@@ -55,5 +50,5 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh, axis: str):
 
     wspec = jax.tree.map(lambda _: P(axis), stage_params)
     xspec = P(*([None] * x.ndim))
-    return shard_map(body, mesh=mesh, in_specs=(wspec, xspec),
-                     out_specs=xspec, check_rep=False)(stage_params, x)
+    return jax.shard_map(body, mesh=mesh, in_specs=(wspec, xspec),
+                         out_specs=xspec, check_vma=False)(stage_params, x)

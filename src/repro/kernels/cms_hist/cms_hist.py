@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.sketch import HIST_BINS
+from repro.kernels.backend import resolve_interpret
 
 DEFAULT_SEG = 512
 
@@ -41,7 +42,8 @@ def hist_pallas(
     epochs_row0: jax.Array,   # (W,) int32
     cur_epoch: jax.Array,     # () int32
     edges: jax.Array,         # (HIST_BINS + 1,) int32
-    *, seg: int = DEFAULT_SEG, width: int = 1 << 14, interpret: bool = True,
+    *, seg: int = DEFAULT_SEG, width: int = 1 << 14,
+    interpret: bool | None = None,
 ) -> jax.Array:
     grid = width // seg
     assert grid * seg == width
@@ -59,6 +61,6 @@ def hist_pallas(
         ],
         out_specs=pl.BlockSpec((1, HIST_BINS), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, HIST_BINS), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(counts_row0.reshape(1, -1), epochs_row0.reshape(1, -1), meta, lo_hi)
     return out[0]

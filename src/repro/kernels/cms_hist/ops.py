@@ -14,8 +14,6 @@ from repro.kernels.cms_hist import cms_hist as kh
 @functools.partial(jax.jit, static_argnames=("params", "interpret"))
 def sketch_histogram(state: SketchState, params: SketchParams,
                      interpret: bool | None = None) -> jax.Array:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     edges = jnp.asarray(sk.hist_edges(params.counter_bits))
     return kh.hist_pallas(
         state.counts[0], state.epochs[0].astype(jnp.int32),

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.sketch import PAGE_ID_BITS
+from repro.kernels.backend import resolve_interpret
 
 DEFAULT_SEG = 512  # lanes per sketch segment (multiple of 128)
 
@@ -119,7 +120,7 @@ def sketch_update_pallas(
     cur_epoch: jax.Array,  # () int32
     counter_max: int,
     *, seg: int = DEFAULT_SEG, depth: int = 2, width: int = 1 << 14,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Pass A: returns (new_counts, new_epochs, est (D,S), hot_before (D,S))."""
     s = page_ids.shape[0]
@@ -152,7 +153,7 @@ def sketch_update_pallas(
             jax.ShapeDtypeStruct((depth, s), jnp.int32),
             jax.ShapeDtypeStruct((depth, s), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(page_ids.reshape(1, -1), seeds, meta, counts, epochs, hot)
 
 
@@ -164,7 +165,7 @@ def sketch_mark_hot_pallas(
     is_hot: jax.Array,    # (S,) int32/bool
     seeds: jax.Array,
     *, seg: int = DEFAULT_SEG, depth: int = 2, width: int = 1 << 14,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Pass B: OR the hot bits of every detected-hot element's entries."""
     s = page_ids.shape[0]
@@ -181,5 +182,5 @@ def sketch_mark_hot_pallas(
         ],
         out_specs=pl.BlockSpec((depth, seg), lambda k: (0, k)),
         out_shape=jax.ShapeDtypeStruct((depth, width), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(page_ids.reshape(1, -1), seeds, is_hot.astype(jnp.int32).reshape(1, -1), hot)

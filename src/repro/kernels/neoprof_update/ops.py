@@ -16,10 +16,6 @@ from repro.core.sketch import SketchParams, SketchState, _first_occurrence
 from repro.kernels.neoprof_update import neoprof_update as ku
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("params", "interpret"))
 def sketch_update(
     state: SketchState,
@@ -28,7 +24,6 @@ def sketch_update(
     params: SketchParams,
     interpret: bool | None = None,
 ) -> tuple[SketchState, jax.Array]:
-    interpret = _interpret_default() if interpret is None else interpret
     valid = page_ids >= 0
     counts = state.counts
     epochs = state.epochs.astype(jnp.int32)

@@ -11,6 +11,10 @@
 ``combine_stats``            — the cross-shard combine (pmax/psum pair);
     given the page partials it also returns each LOCAL page's share of the
     GLOBAL softmax mass, normalized by the same pair.
+
+``interpret=None`` (the default everywhere) takes the backend's answer:
+compiled on a TPU, interpreted on the CPU backend
+(:func:`repro.kernels.backend.resolve_interpret`).
 """
 from __future__ import annotations
 
@@ -27,15 +31,9 @@ __all__ = ["paged_attention", "paged_attention_local_stats", "combine_stats",
            "page_mass"]
 
 
-def _interp():
-    return jax.default_backend() != "tpu"
-
-
 def paged_attention(q, k_pages, v_pages, page_lengths, *,
                     scale=None, softcap: float = 0.0, interpret=None,
                     return_mass: bool = False):
-    if interpret is None:
-        interpret = _interp()
     return _kernel(q, k_pages, v_pages, page_lengths,
                    scale=scale, softcap=softcap, interpret=interpret,
                    return_mass=return_mass)
@@ -45,8 +43,6 @@ def paged_attention_local_stats(q, k_pages, v_pages, page_lengths, *,
                                 scale=None, softcap: float = 0.0,
                                 interpret=None,
                                 return_page_stats: bool = False):
-    if interpret is None:
-        interpret = _interp()
     return _kernel_raw(q, k_pages, v_pages, page_lengths,
                        scale=scale, softcap=softcap, interpret=interpret,
                        return_page_stats=return_page_stats)

@@ -27,23 +27,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import resolve_interpret
 
 NEG_INF = -1e30
 
 
 def _paged_attn_kernel(
-    q_ref,        # (1, H, dh)
-    k_ref,        # (1, 1, T, Hkv, dh)  — one page
-    v_ref,        # (1, 1, T, Hkv, dh)
-    len_ref,      # (1, 1) int32 — valid tokens in this page (0 => invalid)
-    m_ref,        # (1, H, 1)  f32 running max
-    l_ref,        # (1, H, 1)  f32 running denom
-    acc_ref,      # (1, H, dh) f32 running numerator
-    pm_ref=None,  # (1, 1, H)  f32 page-local score max (page-stats mode)
-    pl_ref=None,  # (1, 1, H)  f32 page-local denom     (page-stats mode)
-    *, scale: float, softcap: float, groups: int,
+    len_ref,      # (B, P) int32 in SMEM — valid tokens per page (0 = invalid)
+    q_ref,        # (1, Hkv, G, dk)   query heads grouped by their kv head
+    k_ref,        # (1, 1, T, Hkv*dk) one page, kv heads side by side
+    v_ref,        # (1, 1, T, Hkv*dv)
+    m_ref,        # (1, Hkv, G, 1)  f32 running max
+    l_ref,        # (1, Hkv, G, 1)  f32 running denom
+    acc_ref,      # (1, Hkv, G, dv) f32 running numerator
+    pm_ref=None,  # (1, 1, Hkv, G, 1) f32 page-local score max (page stats)
+    pl_ref=None,  # (1, 1, Hkv, G, 1) f32 page-local denom     (page stats)
+    *, scale: float, softcap: float,
 ):
-    p = pl.program_id(1)
+    b, p = pl.program_id(0), pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
@@ -51,47 +54,44 @@ def _paged_attn_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)                      # (H, dh)
-    k = k_ref[0, 0].astype(jnp.float32)                   # (T, Hkv, dh)
-    v = v_ref[0, 0].astype(jnp.float32)
-    t, hkv, dh = k.shape
-    h = q.shape[0]
-    n_valid = len_ref[0, 0]
+    n_valid = len_ref[b, p]
+    hkv, g, dk = q_ref.shape[1:]
+    dv = acc_ref.shape[-1]
+    t = k_ref.shape[2]
+    tok = jax.lax.broadcasted_iota(jnp.int32, (g, t), 1)
+    valid = tok < n_valid
+    # one (G, dk) x (T, dk)^T score matmul per kv head: the GQA group's
+    # queries share the head's page tile, so nothing is repeated
+    for h in range(hkv):
+        q = q_ref[0, h].astype(jnp.float32)                      # (G, dk)
+        k = k_ref[0, 0, :, h * dk:(h + 1) * dk].astype(jnp.float32)
+        v = v_ref[0, 0, :, h * dv:(h + 1) * dv].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale          # (G, T)
+        if softcap > 0.0:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(valid, s, NEG_INF)
 
-    # GQA: repeat kv heads across the query-head groups.
-    k = jnp.repeat(k, groups, axis=1)                     # (T, H, dh)
-    v = jnp.repeat(v, groups, axis=1)
+        m_page = jnp.max(s, axis=1, keepdims=True)               # (G, 1)
+        m_prev = m_ref[0, h]
+        m_cur = jnp.maximum(m_prev, m_page)
+        # guard fully-masked pages: keep m finite math stable
+        alpha = jnp.exp(jnp.minimum(m_prev - m_cur, 0.0))
+        p_ij = jnp.where(valid, jnp.exp(s - m_cur), 0.0)
+        l_ref[0, h] = l_ref[0, h] * alpha + jnp.sum(p_ij, axis=1,
+                                                    keepdims=True)
+        acc_ref[0, h] = acc_ref[0, h] * alpha + jnp.dot(
+            p_ij, v, preferred_element_type=jnp.float32)         # (G, dv)
+        m_ref[0, h] = m_cur
 
-    s = jnp.einsum("hd,thd->ht", q, k,
-                   preferred_element_type=jnp.float32) * scale   # (H, T)
-    if softcap > 0.0:
-        s = softcap * jnp.tanh(s / softcap)
-    tok = jax.lax.broadcasted_iota(jnp.int32, (h, t), 1)
-    s = jnp.where(tok < n_valid, s, NEG_INF)
-
-    m_page = jnp.max(s, axis=1)                           # (H,) page-local max
-    m_prev = m_ref[0, :, 0]                               # (H,)
-    m_cur = jnp.maximum(m_prev, m_page)
-    # guard fully-masked pages: keep m finite math stable
-    alpha = jnp.exp(jnp.minimum(m_prev - m_cur, 0.0))
-    p_ij = jnp.exp(s - m_cur[:, None])
-    p_ij = jnp.where(tok < n_valid, p_ij, 0.0)
-
-    l_cur = l_ref[0, :, 0] * alpha + jnp.sum(p_ij, axis=1)
-    acc = acc_ref[0] * alpha[:, None] + jnp.einsum(
-        "ht,thd->hd", p_ij, v, preferred_element_type=jnp.float32)
-
-    m_ref[0, :, 0] = m_cur
-    l_ref[0, :, 0] = l_cur
-    acc_ref[0] = acc
-
-    if pm_ref is not None:
-        # page-local partials under the page's OWN max — rescaled to the
-        # global max outside the kernel (ops.page_mass / combine_stats), so
-        # this page's block never needs revisiting.
-        p_loc = jnp.where(tok < n_valid, jnp.exp(s - m_page[:, None]), 0.0)
-        pm_ref[0, 0] = m_page
-        pl_ref[0, 0] = jnp.sum(p_loc, axis=1)
+        if pm_ref is not None:
+            # page-local partials under the page's OWN max — rescaled to
+            # the global max outside the kernel (ops.page_mass /
+            # combine_stats), so this page's block never needs revisiting.
+            p_loc = jnp.where(valid, jnp.exp(s - m_page), 0.0)
+            pm_ref[0, 0, h] = m_page
+            pl_ref[0, 0, h] = jnp.sum(p_loc, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "softcap", "interpret",
@@ -102,57 +102,73 @@ def paged_attention_raw(
     v_pages: jax.Array,    # (B, P, T, Hkv, dv)
     page_lengths: jax.Array,  # (B, P) int32 — 0 marks an invalid page
     *, scale: float | None = None, softcap: float = 0.0,
-    interpret: bool = True, return_page_stats: bool = False,
+    interpret: bool | None = None, return_page_stats: bool = False,
 ):
     """Unnormalized flash-decode stats (m, l, acc) — for cross-shard combine.
 
     With ``return_page_stats`` the result is (m, l, acc, page_m, page_l)
     where ``page_m``/``page_l`` are the (B, P, H) page-local softmax
     partials (see module docstring) — fully-masked pages report
-    ``page_m = NEG_INF, page_l = 0``.
+    ``page_m = NEG_INF, page_l = 0``.  ``interpret=None`` takes the
+    backend's answer (:func:`repro.kernels.backend.resolve_interpret`).
+
+    Layout for the TPU tiling: page lengths ride in SMEM by scalar
+    prefetch; the kv heads of a page sit side by side on the lane axis
+    (a free reshape of the page layout), and every output block spans its
+    array's last two dimensions.
     """
     b, h, dh = q.shape
-    _, p, t, hkv, _ = k_pages.shape
+    _, p, t, hkv, dk = k_pages.shape
     dv = v_pages.shape[-1]
     groups = h // hkv
     scale = (dh ** -0.5) if scale is None else scale
-    kern = functools.partial(
-        _paged_attn_kernel, scale=scale, softcap=softcap, groups=groups)
+    kern = functools.partial(_paged_attn_kernel, scale=scale, softcap=softcap)
 
     out_specs = [
-        pl.BlockSpec((1, h, 1), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, h, 1), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, h, dv), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((1, hkv, groups, 1), lambda i, j, lens: (i, 0, 0, 0)),
+        pl.BlockSpec((1, hkv, groups, 1), lambda i, j, lens: (i, 0, 0, 0)),
+        pl.BlockSpec((1, hkv, groups, dv), lambda i, j, lens: (i, 0, 0, 0)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
-        jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
-        jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
+        jax.ShapeDtypeStruct((b, hkv, groups, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b, hkv, groups, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b, hkv, groups, dv), jnp.float32),
     ]
     if return_page_stats:
-        out_specs += [pl.BlockSpec((1, 1, h), lambda i, j: (i, j, 0)),
-                      pl.BlockSpec((1, 1, h), lambda i, j: (i, j, 0))]
-        out_shape += [jax.ShapeDtypeStruct((b, p, h), jnp.float32),
-                      jax.ShapeDtypeStruct((b, p, h), jnp.float32)]
+        stat = pl.BlockSpec((1, 1, hkv, groups, 1),
+                            lambda i, j, lens: (i, j, 0, 0, 0))
+        out_specs += [stat, stat]
+        out_shape += [jax.ShapeDtypeStruct((b, p, hkv, groups, 1),
+                                           jnp.float32)] * 2
 
     outs = pl.pallas_call(
         kern,
-        grid=(b, p),
-        in_specs=[
-            pl.BlockSpec((1, h, dh), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 1, t, hkv, dh), lambda i, j: (i, j, 0, 0, 0)),
-            pl.BlockSpec((1, 1, t, hkv, dv), lambda i, j: (i, j, 0, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, p),
+            in_specs=[
+                pl.BlockSpec((1, hkv, groups, dk),
+                             lambda i, j, lens: (i, 0, 0, 0)),
+                pl.BlockSpec((1, 1, t, hkv * dk),
+                             lambda i, j, lens: (i, j, 0, 0)),
+                pl.BlockSpec((1, 1, t, hkv * dv),
+                             lambda i, j, lens: (i, j, 0, 0)),
+            ],
+            out_specs=out_specs),
         out_shape=out_shape,
-        interpret=interpret,
-    )(q, k_pages, v_pages, page_lengths.astype(jnp.int32))
-    return tuple(outs)
+        interpret=resolve_interpret(interpret),
+        name="paged_attn",
+    )(page_lengths.astype(jnp.int32), q.reshape(b, hkv, groups, dh),
+      k_pages.reshape(b, p, t, hkv * dk), v_pages.reshape(b, p, t, hkv * dv))
+    m, l, acc = (o.reshape(b, h, -1) for o in outs[:3])
+    if not return_page_stats:
+        return m, l, acc
+    return (m, l, acc) + tuple(o.reshape(b, p, h) for o in outs[3:])
 
 
 def paged_attention(q, k_pages, v_pages, page_lengths, *,
-                    scale=None, softcap: float = 0.0, interpret: bool = True,
+                    scale=None, softcap: float = 0.0,
+                    interpret: bool | None = None,
                     return_mass: bool = False):
     """Normalized paged decode attention.
 

@@ -93,8 +93,6 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out: dict,
                     "alias_bytes": int(ma.alias_size_in_bytes),
                 }
             ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):   # jax<=0.4 returns [dict]
-                ca = ca[0] if ca else {}
             print({k: ca.get(k) for k in ("flops", "bytes accessed")})
             if ca:
                 rec["cost"] = {

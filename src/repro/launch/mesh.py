@@ -8,30 +8,26 @@ from __future__ import annotations
 import jax
 
 
-def _make(shape, axes):
-    # jax >= 0.5 takes explicit axis types; 0.4.x has neither the kwarg nor
-    # jax.sharding.AxisType — Auto is the default there anyway.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """A mesh whose axes are all Auto: the partitioner propagates shardings
+    and ``with_sharding_constraint`` pins them (JAX's own default is
+    Explicit axes, where shardings are part of every array's type)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh over however many devices exist (tests / examples)."""
     n = len(jax.devices())
     data = n // model
-    return _make((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

@@ -465,7 +465,6 @@ def _append_attend_sharded(kp, vp, plen, cur_slot, k_new, v_new, q_eff, *,
     the only per-step collective is O(B x H x dv).  The kernel's per-page
     partials are normalized by the SAME pair, so the (B, n_slots) global
     softmax-mass stream comes back shard-assembled for free."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh, axes = smesh["mesh"], smesh["axes"]
 
@@ -513,12 +512,12 @@ def _append_attend_sharded(kp, vp, plen, cur_slot, k_new, v_new, q_eff, *,
     out_specs = (rep, pagespec, pagespec, P(None, axes), P(None))
     if collect_mass:
         out_specs += (P(None, axes),)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pagespec, pagespec, P(None, axes), P(None),
                   rep, rep, rep),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(kp, vp, plen, cur_slot, k_new, v_new, q_eff)
     return out if collect_mass else out + (None,)
 

@@ -261,19 +261,10 @@ def moe_apply_ep(p, x, k: int, *, bias=None, ep_axes: EPContext | None = None):
     NeoMem profiling stream.
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax
-        from jax.sharding import shard_map  # type: ignore
 
     e = p["router"].shape[1]
 
     if "residency" in p:   # NeoMem-tiered serving path (hot experts resident)
-        from jax.sharding import PartitionSpec as P
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:
-            from jax.sharding import shard_map  # type: ignore
         b, s, d = x.shape
         # resident path: dispatch buffers sized to expected load (x8 head-
         # room), NOT to the no-drop bound — with E_hot+fetch local experts a
@@ -292,14 +283,14 @@ def moe_apply_ep(p, x, k: int, *, bias=None, ep_axes: EPContext | None = None):
             wspec = P(ep.expert_axis, None, None)
             # fetch buffers + ids are sharded over the EP axis too: each
             # shard DMA's its own cold experts under the migration quota
-            y, idx = shard_map(
+            y, idx = jax.shard_map(
                 body, mesh=ep.mesh,
                 in_specs=(rep3, P(None, None),
                           P(None) if bias is not None else None, P(None),
                           wspec, wspec, wspec, wspec, wspec, wspec,
                           P(ep.expert_axis)),
                 out_specs=(rep3, rep3),
-                check_rep=False,
+                check_vma=False,
             )(*args)
         return y + _shared_expert(p, x), idx, None
 
@@ -325,12 +316,12 @@ def moe_apply_ep(p, x, k: int, *, bias=None, ep_axes: EPContext | None = None):
             expert_axis=ep.expert_axis, fsdp_axis=ep.fsdp_axis)
         dp = P(dp_axes, None, None) if dp_axes else P(None, None, None)
         wspec = P(ep.expert_axis, ep.fsdp_axis, None)
-        y, idx = shard_map(
+        y, idx = jax.shard_map(
             body, mesh=ep.mesh,
             in_specs=(dp, P(None, None), P(None) if bias is not None else None,
                       wspec, wspec, wspec),
             out_specs=(dp, dp),
-            check_rep=False,
+            check_vma=False,
         )(x, p["router"], bias, p["w_gate"], p["w_in"], p["w_out"])
 
     y = y + _shared_expert(p, x)

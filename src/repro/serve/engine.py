@@ -311,6 +311,17 @@ class ServeEngine:
                 [table, jnp.zeros((pad, d), table.dtype)], axis=0)
         return table.reshape(n_pages, rows_per_page, d)
 
+    def _commit(self, cache):
+        """Commit a fresh cache to the device the params live on.  A jitted
+        step's outputs are committed arrays; a first call with uncommitted
+        cache leaves would compile the step a second time on the next
+        call.  Params spread over several devices (EP serving) leave the
+        cache to the step's own sharding."""
+        devices = jax.tree_util.tree_leaves(self.params)[0].devices()
+        if len(devices) != 1:
+            return cache
+        return jax.device_put(cache, next(iter(devices)))
+
     # -- jitted step bodies -------------------------------------------------
     def _decode_fn(self, params, cache, token, aux, tiered):
         return dec.decode_step(self.cfg, params, cache, token,
@@ -387,8 +398,8 @@ class ServeEngine:
         if self.cfg.encoder_layers and aux_embeds is not None:
             self.aux = tr.encode(self.cfg, self.params, aux_embeds)
         if self.scfg.paged:
-            self.cache = dec.init_paged_cache(
-                self.cfg, b, self.scfg.hot_slots, self.scfg.page_t)
+            self.cache = self._commit(dec.init_paged_cache(
+                self.cfg, b, self.scfg.hot_slots, self.scfg.page_t))
             self._kv_flushed.clear()         # fresh ring: re-flush everything
             # chunked prefill: scan the paged decode body over the prompt in
             # ring-capacity chunks (bit-exact with token-at-a-time streaming;
@@ -402,7 +413,8 @@ class ServeEngine:
         # dense path: ONE scan fills the cache and yields the last-token
         # logits together — the prompt runs exactly once, and the tiering
         # streams are replayed as one masked observation batch
-        self.cache = dec.init_cache(self.cfg, b, self.scfg.max_seq)
+        self.cache = self._commit(dec.init_cache(self.cfg, b,
+                                                 self.scfg.max_seq))
         logits, self.cache, streams = self._prefill_dense_jit(
             self.params, self.cache, jnp.asarray(tokens), self.aux,
             self._tier_reads())
@@ -471,8 +483,9 @@ class ServeEngine:
         scfg = self.scfg
         if not self.lane_mode:
             raise ValueError("start_lanes requires ServeConfig.lanes > 0")
-        self.cache = dec.init_paged_cache(self.cfg, scfg.lanes, scfg.hot_slots,
-                                          scfg.page_t, per_lane_pos=True)
+        self.cache = self._commit(dec.init_paged_cache(
+            self.cfg, scfg.lanes, scfg.hot_slots, scfg.page_t,
+            per_lane_pos=True))
         # pristine one-lane template: reset_lane restores INITIAL values,
         # which are not all zero (the m/sLSTM stabilizer state inits to -inf)
         self._lane_init = dec.init_paged_cache(self.cfg, 1, scfg.hot_slots,

@@ -405,12 +405,7 @@ class TieredMemory:
         occupied = np.flatnonzero(slot_page >= 0)
         if occupied.size == 0:
             return
-        pages = slot_page[occupied]
-        scale = self.buffers.scale
-        rows = codec_lib.decode_rows(
-            self.buffers.slow[pages],
-            None if scale is None else scale[pages],
-            self.buffers.fast.dtype)
+        rows = migrate_lib.gather_rows(self.buffers, slot_page[occupied])
         fast = self.buffers.fast.at[occupied].set(rows)
         self.buffers = self.buffers._replace(fast=fast)
 
@@ -458,23 +453,16 @@ class TieredMemory:
         slots_np = np.asarray(slots)
         ids_np = np.maximum(np.asarray(page_ids), 0)
         hit = slots_np >= 0
-
-        def _slow(ids):     # slow-store gather + wire-format decode
-            scale = self.buffers.scale
-            return codec_lib.decode_rows(
-                self.buffers.slow[ids],
-                None if scale is None else scale[ids],
-                self.buffers.fast.dtype)
-
         if hit.all():
             return self.buffers.fast[slots]
         if not hit.any():
-            return _slow(ids_np)
+            return migrate_lib.gather_rows(self.buffers, ids_np)
         rows = jnp.empty(page_ids.shape + self.buffers.fast.shape[1:],
                          self.buffers.fast.dtype)
         rows = rows.at[np.flatnonzero(hit)].set(
             self.buffers.fast[slots_np[hit]])
-        return rows.at[np.flatnonzero(~hit)].set(_slow(ids_np[~hit]))
+        return rows.at[np.flatnonzero(~hit)].set(
+            migrate_lib.gather_rows(self.buffers, ids_np[~hit]))
 
     def write_rows(self, state: TieredMemoryState, page_ids, rows) -> int:
         """Refresh page payloads in both tiers (owners with mutating data):
@@ -496,21 +484,22 @@ class TieredMemory:
 
     def write_pages(self, state: TieredMemoryState, page_ids, k_pages,
                     v_pages) -> int:
-        """Bulk KV ring-page flush (:func:`migrate.write_pages`): the [K|V]
-        concat, slot-major transpose and dual-tier scatter fuse in one
-        donated jit — the chunked-prefill data-plane verb.  ``k_pages`` /
-        ``v_pages`` are (G, L, S, T, hkv, d) ring views; ``page_ids`` the
-        (L*S,) slot map (-1 = dropped).  Returns the pages written."""
+        """Bulk KV ring-page flush (:func:`migrate.write_pages`): the
+        written slots' gather, [K|V] concat and dual-tier scatter fuse in
+        one donated jit — the chunked-prefill data-plane verb.  ``k_pages``
+        / ``v_pages`` are (G, L, S, T, hkv, d) ring views; ``page_ids`` the
+        (L*S,) slot map (-1 = not written), compacted here so only written
+        pages move.  Returns the pages written."""
         if self.buffers is None:
             raise ValueError("no payload bound — call bind_data() first")
-        page_ids = jnp.asarray(page_ids, jnp.int32)
+        ring, page_ids = migrate_lib.ring_selection(page_ids)
         slots = self.lookup_slots(state, page_ids)
         self.buffers = migrate_lib.write_pages(self.buffers, page_ids, slots,
-                                               k_pages, v_pages,
+                                               ring, k_pages, v_pages,
                                                codec=self.codec)
         if self._inflight is not None:
             self._inflight.fast = migrate_lib.refresh_pages(
-                self._inflight.fast, self._inflight_slots(page_ids),
+                self._inflight.fast, self._inflight_slots(page_ids), ring,
                 k_pages, v_pages)
         return self._mark_written(page_ids)
 

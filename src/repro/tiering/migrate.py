@@ -9,10 +9,8 @@ this module moves them.  A resource that binds payload data gets a
   * ``slow``: ``(num_pages, *row_shape)`` — the full backing store in the
     resource's wire format (:mod:`repro.tiering.codec`, DESIGN.md §14):
     native dtype under the ``none`` codec, fp32 under ``fp32``, int8 under
-    ``int8``.  Placed in the ``pinned_host`` slow tier when the backend
-    supports memory kinds (:mod:`repro.dist.host_offload`), or kept as a
-    logically-separate device array on the CPU fallback so the data path
-    runs unchanged in CI;
+    ``int8``.  Always placed in the ``pinned_host`` slow tier
+    (:mod:`repro.dist.host_offload`); a device without one is an error;
   * ``scale``: ``(num_pages,)`` fp32 per-row quantization scales — present
     only under the ``int8`` codec (``None`` otherwise).
 
@@ -23,6 +21,11 @@ slots (DECODED back to native dtype inside the same jit).  Both buffers
 are donated on accelerators, so the epoch costs exactly the moved WIRE
 bytes — which the caller meters against the per-epoch byte quota in
 :class:`~repro.tiering.stats.TierStats`.
+
+Every verb reaches the host store through :func:`host_offload.host_take`
+/ :func:`host_offload.host_put`: only the rows they name cross the
+host/device boundary, so a verb's transfer scales with its batch, never
+with the store.
 
 The read verbs (:func:`read_rows` / :func:`lookup_rows`) never take a
 codec name: decode dispatches on the payload dtype and scale presence
@@ -64,19 +67,17 @@ def row_bytes(buffers: TierBuffers) -> int:
 
 
 def place_slow(x: jax.Array) -> jax.Array:
-    """Place the backing store in the slow tier (pinned host when available).
-
-    On TPU/GPU this carries a ``pinned_host`` memory-kind sharding and XLA
-    emits real H2D/D2H copies for every gather/scatter against it; on CPU
-    the tiers degrade to logical separation (DESIGN.md §7) and the data
-    path is exercised bit-for-bit without the placement.
-    """
-    x = jnp.asarray(x)
-    if not ho.supports_memory_kinds():
-        return x
+    """Place the backing store in the ``pinned_host`` slow tier of the
+    device ``x`` lives on (DESIGN.md §7).  Raises when that device has no
+    host tier or the store did not land there."""
     from jax.sharding import Mesh, PartitionSpec as P
-    mesh = Mesh(np.asarray(jax.devices()[:1]), ("_tier",))
-    return ho.to_slow_tier(x, mesh, P())
+    x = jnp.asarray(x)
+    (device,) = x.devices()
+    out = ho.to_slow_tier(x, Mesh(np.asarray([device]), ("_tier",)), P())
+    if out.sharding.memory_kind != ho.SLOW_KIND:
+        raise RuntimeError(f"slow store landed in {out.sharding.memory_kind!r}"
+                           f", not {ho.SLOW_KIND!r}")
+    return out
 
 
 def init_buffers(slow_data: jax.Array, num_slots: int,
@@ -94,7 +95,11 @@ def init_buffers(slow_data: jax.Array, num_slots: int,
     slow = place_slow(payload)
     if scale is not None:
         scale = place_slow(scale)
-    fast = jnp.zeros((num_slots,) + slow.shape[1:], slow_data.dtype)
+    # committed to the store's device, like every later verb's output, so
+    # the first jitted read compiles the same program as the rest
+    (device,) = slow.devices()
+    fast = jax.device_put(
+        jnp.zeros((num_slots,) + slow.shape[1:], slow_data.dtype), device)
     return TierBuffers(fast=fast, slow=slow, scale=scale)
 
 
@@ -129,7 +134,42 @@ def _donate(n_buffers: int):
 
 def _scale_at(scale, idx):
     """Per-row scales for a gathered id batch (None under scale-less codecs)."""
-    return None if scale is None else scale[idx]
+    return None if scale is None else ho.host_take(scale, idx)
+
+
+def _slow_rows(slow, scale, idx, dtype):
+    """Decoded slow-store rows for an id batch (host gather + decode)."""
+    return codec_lib.decode_rows(ho.host_take(slow, idx),
+                                 _scale_at(scale, idx), dtype)
+
+
+def _put_store(slow, scale, idx, payload, row_scale):
+    """Scatter wire-format rows (and their scales) into the slow store."""
+    slow = ho.host_put(slow, idx, payload)
+    if scale is not None:
+        scale = ho.host_put(scale, idx, row_scale)
+    return slow, scale
+
+
+def _rehosted(buffers: TierBuffers, fast, slow, scale) -> TierBuffers:
+    """New buffers from a write verb, the store back under ``buffers``'
+    own placement (:func:`host_offload.rehost`)."""
+    return TierBuffers(
+        fast=fast, slow=ho.rehost(slow, buffers.slow.sharding),
+        scale=None if scale is None else ho.rehost(scale,
+                                                   buffers.scale.sharding))
+
+
+@jax.jit
+def _gather_jit(fast, slow, scale, idx):
+    return _slow_rows(slow, scale, idx, fast.dtype)
+
+
+def gather_rows(buffers: TierBuffers, page_ids) -> jax.Array:
+    """Decoded slow-store rows for ``page_ids`` (a host verb: one jitted
+    host-side gather; only the named rows cross to the device)."""
+    return _gather_jit(buffers.fast, buffers.slow, buffers.scale,
+                       jnp.asarray(page_ids, jnp.int32))
 
 
 def _migrate_impl(codec, fast, slow, scale, promoted, victims, evicted):
@@ -140,8 +180,7 @@ def _migrate_impl(codec, fast, slow, scale, promoted, victims, evicted):
     # this batch is never also evicted in it, but order still documents it);
     # promotion is the decode point — fast rows are native dtype
     up_idx = jnp.where(ok, promoted, 0)
-    gathered = codec_lib.decode_rows(slow[up_idx], _scale_at(scale, up_idx),
-                                     fast.dtype)
+    gathered = _slow_rows(slow, scale, up_idx, fast.dtype)
     # no-op lanes scatter out of bounds and are dropped — routing them to
     # index 0 would race with a legitimate write to page/slot 0
     ev_idx = jnp.where(ev_ok, evicted, n_pages)
@@ -150,9 +189,7 @@ def _migrate_impl(codec, fast, slow, scale, promoted, victims, evicted):
     # re-encoded to the wire format (the codec's quantize point)
     down, down_scale = codec_lib.encode_rows(
         codec, fast[jnp.where(ev_ok, victims, 0)])
-    slow = slow.at[ev_idx].set(down.astype(slow.dtype), mode="drop")
-    if scale is not None:
-        scale = scale.at[ev_idx].set(down_scale, mode="drop")
+    slow, scale = _put_store(slow, scale, ev_idx, down, down_scale)
     # promotion: hot rows land in the freed slots
     fast = fast.at[sl_idx].set(gathered, mode="drop")
     return (fast, slow, scale, jnp.sum(ok, dtype=jnp.int32),
@@ -181,8 +218,7 @@ def migrate(buffers: TierBuffers, promoted: jax.Array, victims: jax.Array,
         buffers.fast, buffers.slow, buffers.scale,
         jnp.asarray(promoted, jnp.int32), jnp.asarray(victims, jnp.int32),
         jnp.asarray(evicted, jnp.int32))
-    return TierBuffers(fast=fast, slow=slow, scale=scale), int(n_up), \
-        int(n_down)
+    return _rehosted(buffers, fast, slow, scale), int(n_up), int(n_down)
 
 
 def read_rows(fast: jax.Array, slow: jax.Array, slots: jax.Array,
@@ -199,8 +235,7 @@ def read_rows(fast: jax.Array, slow: jax.Array, slots: jax.Array,
     """
     hit = slots >= 0
     safe_page = jnp.where(page_ids >= 0, page_ids, 0)
-    slow_rows = codec_lib.decode_rows(
-        slow[safe_page], _scale_at(scale, safe_page), fast.dtype)
+    slow_rows = _slow_rows(slow, scale, safe_page, fast.dtype)
     mask = hit.reshape(hit.shape + (1,) * (fast.ndim - 1))
     return jnp.where(mask, fast[jnp.where(hit, slots, 0)], slow_rows)
 
@@ -232,9 +267,7 @@ def lookup_rows(fast: jax.Array, slow: jax.Array, page_slot: jax.Array,
 def _write_rows_impl(codec, fast, slow, scale, page_ids, slots, rows):
     payload, row_scale = codec_lib.encode_rows(codec, rows)
     slow_idx = jnp.where(page_ids >= 0, page_ids, slow.shape[0])
-    slow = slow.at[slow_idx].set(payload.astype(slow.dtype), mode="drop")
-    if scale is not None:
-        scale = scale.at[slow_idx].set(row_scale, mode="drop")
+    slow, scale = _put_store(slow, scale, slow_idx, payload, row_scale)
     # keep promoted copies coherent: a page resident in the fast tier gets
     # its fast row refreshed too (native dtype — the fast tier never holds
     # wire format), so later reads/write-backs never serve a stale snapshot
@@ -264,19 +297,36 @@ def write_rows(buffers: TierBuffers, page_ids: jax.Array, slots: jax.Array,
         buffers.fast, buffers.slow, buffers.scale,
         jnp.asarray(page_ids, jnp.int32), jnp.asarray(slots, jnp.int32),
         rows)
-    return TierBuffers(fast=fast, slow=slow, scale=scale)
+    return _rehosted(buffers, fast, slow, scale)
 
 
-def _pages_to_rows(k_pages, v_pages):
-    # ring layout (G, L, S, T, hkv, d) -> page-row layout (L*S, G, T, hkv, d)
-    rows = jnp.concatenate([k_pages, v_pages], axis=-1)
-    rows = jnp.moveaxis(rows, 0, 2)
-    return rows.reshape((-1,) + rows.shape[2:])
+def ring_selection(page_ids) -> tuple[np.ndarray, np.ndarray]:
+    """Compact a (L*S,) ring-slot -> page map (-1 = slot not written) to
+    the slots that write: ``(ring slot indices, their page ids)``, padded
+    to the next power of two with slot 0 / page -1 (a dropped lane), so a
+    flush moves only the written pages and compiles once per size bucket."""
+    ids = np.asarray(page_ids).reshape(-1)
+    sel = np.flatnonzero(ids >= 0)
+    n = 1 << max(int(sel.size) - 1, 0).bit_length()
+    ring = np.zeros(n, np.int32)
+    ring[:sel.size] = sel
+    pids = np.full(n, -1, np.int32)
+    pids[:sel.size] = ids[sel]
+    return ring, pids
 
 
-def _write_pages_impl(codec, fast, slow, scale, page_ids, slots,
+def _pages_to_rows(k_pages, v_pages, ring):
+    # ring layout (G, L, S, T, hkv, d), ring slots ``ring`` of the flattened
+    # L*S axis -> page-row layout (n, G, T, hkv, dk+dv)
+    def take(x):
+        return x.reshape((x.shape[0], -1) + x.shape[3:])[:, ring]
+    rows = jnp.concatenate([take(k_pages), take(v_pages)], axis=-1)
+    return jnp.moveaxis(rows, 0, 1)
+
+
+def _write_pages_impl(codec, fast, slow, scale, page_ids, slots, ring,
                       k_pages, v_pages):
-    rows = _pages_to_rows(k_pages, v_pages)
+    rows = _pages_to_rows(k_pages, v_pages, ring)
     return _write_rows_impl(codec, fast, slow, scale, page_ids, slots, rows)
 
 
@@ -287,23 +337,24 @@ def _write_pages_jit(codec: str):
 
 
 def write_pages(buffers: TierBuffers, page_ids: jax.Array, slots: jax.Array,
-                k_pages: jax.Array, v_pages: jax.Array,
+                ring: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                 codec: str = "none") -> TierBuffers:
     """Bulk KV-page write: flush paged-ring slots into the tier store as ONE
     donated fused op (the chunked-prefill / lane-flush data-plane verb).
 
     ``k_pages`` / ``v_pages`` are ring views shaped (G, L, S, T, hkv, dk|dv)
-    — layer groups x lanes x ring slots; ``page_ids`` is the (L*S,) slot ->
-    logical-page map (-1 = unchanged/dropped slot) and ``slots`` its
-    placement lookup.  The [K | V] concat, slot-major transpose, codec
-    encode and dual-tier scatter all fuse inside one jit, so a chunk flush
-    costs one dispatch instead of the host-side reshape pipeline + scatter.
+    — layer groups x lanes x ring slots; ``ring`` / ``page_ids`` are the
+    written ring slots (indices into the flattened L*S axis) and their
+    logical pages, as :func:`ring_selection` compacts them (-1 = dropped
+    lane), and ``slots`` the pages' placement lookup.  The slot gather,
+    [K | V] concat, codec encode and dual-tier scatter all fuse inside one
+    jit; only the selected pages cross to the host store.
     """
     fast, slow, scale = _write_pages_jit(codec)(
         buffers.fast, buffers.slow, buffers.scale,
         jnp.asarray(page_ids, jnp.int32), jnp.asarray(slots, jnp.int32),
-        k_pages, v_pages)
-    return TierBuffers(fast=fast, slow=slow, scale=scale)
+        jnp.asarray(ring, jnp.int32), k_pages, v_pages)
+    return _rehosted(buffers, fast, slow, scale)
 
 
 # -- async data plane (DESIGN.md §15) ---------------------------------------
@@ -323,8 +374,7 @@ def write_pages(buffers: TierBuffers, page_ids: jax.Array, slots: jax.Array,
 def _issue_migrate_jit(fast, slow, scale, promoted, victims):
     ok = (promoted >= 0) & (victims >= 0)
     up_idx = jnp.where(ok, promoted, 0)
-    gathered = codec_lib.decode_rows(slow[up_idx], _scale_at(scale, up_idx),
-                                     fast.dtype)
+    gathered = _slow_rows(slow, scale, up_idx, fast.dtype)
     sl_idx = jnp.where(ok, victims, fast.shape[0])
     new_fast = fast.at[sl_idx].set(gathered, mode="drop")
     return new_fast, jnp.sum(ok, dtype=jnp.int32)
@@ -371,8 +421,9 @@ def refresh_rows(fast: jax.Array, slots: jax.Array, rows: jax.Array
     return _refresh_rows_jit()(fast, jnp.asarray(slots, jnp.int32), rows)
 
 
-def _refresh_pages_impl(fast, slots, k_pages, v_pages):
-    return _refresh_rows_impl(fast, slots, _pages_to_rows(k_pages, v_pages))
+def _refresh_pages_impl(fast, slots, ring, k_pages, v_pages):
+    return _refresh_rows_impl(fast, slots,
+                              _pages_to_rows(k_pages, v_pages, ring))
 
 
 @functools.lru_cache(maxsize=None)
@@ -380,17 +431,18 @@ def _refresh_pages_jit():
     return jax.jit(_refresh_pages_impl, donate_argnums=_donate(1))
 
 
-def refresh_pages(fast: jax.Array, slots: jax.Array, k_pages: jax.Array,
-                  v_pages: jax.Array) -> jax.Array:
-    """Bulk-flush analogue of :func:`refresh_rows` for KV ring views."""
+def refresh_pages(fast: jax.Array, slots: jax.Array, ring: jax.Array,
+                  k_pages: jax.Array, v_pages: jax.Array) -> jax.Array:
+    """Bulk-flush analogue of :func:`refresh_rows` for KV ring views
+    (``ring`` as in :func:`write_pages`)."""
     return _refresh_pages_jit()(fast, jnp.asarray(slots, jnp.int32),
-                                k_pages, v_pages)
+                                jnp.asarray(ring, jnp.int32), k_pages,
+                                v_pages)
 
 
 def _refresh_copy_impl(fast, slow, scale, src_ids, dst_slots):
     src_safe = jnp.maximum(src_ids, 0)
-    rows = codec_lib.decode_rows(slow[src_safe], _scale_at(scale, src_safe),
-                                 fast.dtype)
+    rows = _slow_rows(slow, scale, src_safe, fast.dtype)
     idx = jnp.where((src_ids >= 0) & (dst_slots >= 0), dst_slots,
                     fast.shape[0])
     return fast.at[idx].set(rows, mode="drop")
@@ -417,13 +469,11 @@ def _copy_rows_impl(fast, slow, scale, src_ids, dst_ids, dst_slots):
     # and copies the WIRE format verbatim (payload + scale): a quantized
     # page publishes without a decode/re-encode round trip
     src_safe = jnp.maximum(src_ids, 0)
-    rows = slow[src_safe]
+    rows = ho.host_take(slow, src_safe)
     src_scale = _scale_at(scale, src_safe)   # gather BEFORE the scatter below
     valid = (src_ids >= 0) & (dst_ids >= 0)
     slow_idx = jnp.where(valid, dst_ids, slow.shape[0])
-    slow = slow.at[slow_idx].set(rows, mode="drop")
-    if scale is not None:
-        scale = scale.at[slow_idx].set(src_scale, mode="drop")
+    slow, scale = _put_store(slow, scale, slow_idx, rows, src_scale)
     fast_idx = jnp.where(valid & (dst_slots >= 0), dst_slots, fast.shape[0])
     fast = fast.at[fast_idx].set(
         codec_lib.decode_rows(rows, src_scale, fast.dtype), mode="drop")
@@ -450,4 +500,4 @@ def copy_rows(buffers: TierBuffers, src_ids: jax.Array, dst_ids: jax.Array,
         buffers.fast, buffers.slow, buffers.scale,
         jnp.asarray(src_ids, jnp.int32), jnp.asarray(dst_ids, jnp.int32),
         jnp.asarray(dst_slots, jnp.int32))
-    return TierBuffers(fast=fast, slow=slow, scale=scale)
+    return _rehosted(buffers, fast, slow, scale)

@@ -22,11 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:                                # jax<=0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map
-except ImportError:                 # newer jax promoted it to the top level
-    from jax import shard_map       # type: ignore
-
 from repro.configs.base import ArchConfig
 from repro.core.neoprof import NeoProfParams, neoprof_init, neoprof_observe
 from repro.core.sketch import SketchParams
@@ -70,11 +65,11 @@ def build_train_step(cfg: ArchConfig, mesh, tcfg: TrainConfig = TrainConfig()):
     prof_params = NeoProfParams(sketch=SketchParams(width=tcfg.sketch_width))
     z1spec = None
     if tcfg.zero1:
-        # the flat spec is trace-time static (shapes + treedef only), so it
-        # lives in the closure, never in the jitted state pytree
+        # the shard spec is trace-time static (shapes + treedef only), so
+        # it lives in the closure, never in the jitted state pytree
         p_shapes = jax.eval_shape(
             lambda: tr.init_params(cfg, jax.random.PRNGKey(0)))
-        z1spec = zero1.flat_spec(p_shapes, zero1._n_shards(mesh))
+        z1spec = zero1.shard_spec(p_shapes, zero1._n_shards(mesh))
 
     def loss_fn(params, mb):
         loss, (metrics, aux) = tr.train_loss(cfg, params, mb,
@@ -88,7 +83,7 @@ def build_train_step(cfg: ArchConfig, mesh, tcfg: TrainConfig = TrainConfig()):
             # promote the parked master vectors FIRST: the fetch has no data
             # dependency on the grads, so XLA overlaps the host→device copy
             # with the whole backward below (prefetch-before-optimizer-step)
-            opt_state = zero1.fetch_opt(opt_state, mesh)
+            opt_state = zero1.fetch_opt(opt_state, mesh, z1spec)
 
         def micro(carry, mb):
             gacc, lacc = carry
@@ -137,22 +132,21 @@ def build_train_step(cfg: ArchConfig, mesh, tcfg: TrainConfig = TrainConfig()):
                     gsum, ef_l = compression.compress_psum(gsum, ef_l, dp)
                 else:
                     gsum = jax.lax.psum(gsum, dp)
-                lsum = jax.lax.psum(lsum, dp) / jax.lax.psum(1.0, dp)
-                return gsum, lsum, ef_l
+                # each shard's loss is the mean over its own rows: the
+                # global mean (and its gradient) is the mean over shards
+                n = jax.lax.psum(1.0, dp)
+                gsum = jax.tree.map(lambda g: g / n, gsum)
+                return gsum, jax.lax.psum(lsum, dp) / n, ef_l
 
             pspec = jax.tree.map(lambda _: P(), params)
             mspec = jax.tree.map(lambda _: P(None, dp), mbs)
             ef_in = state["ef"] if dp_compress else jax.tree.map(
                 lambda _: jnp.zeros((0,), jnp.float32), params)
-            smap_kw = dict(mesh=mesh,
-                           in_specs=(pspec, mspec, pspec),
-                           out_specs=(pspec, P(), pspec),
-                           check_rep=False)
-            other = frozenset(mesh.axis_names) - frozenset(dp)
-            if other:       # leave non-DP axes to the partitioner
-                smap_kw["auto"] = other
-            gsum, lsum, new_ef = shard_map(grad_loop, **smap_kw)(
-                params, mbs, ef_in)
+            # manual over the DP axes only: the partitioner keeps the rest
+            gsum, lsum, new_ef = jax.shard_map(
+                grad_loop, mesh=mesh, in_specs=(pspec, mspec, pspec),
+                out_specs=(pspec, P(), pspec), axis_names=frozenset(dp),
+                check_vma=False)(params, mbs, ef_in)
             streams = None
         else:
             (gsum, lsum), streams = jax.lax.scan(micro, (zero_g, 0.0), mbs)
@@ -176,7 +170,7 @@ def build_train_step(cfg: ArchConfig, mesh, tcfg: TrainConfig = TrainConfig()):
                 tcfg.opt, params, grads, opt_state, z1spec, mesh,
                 compress_collective=tcfg.compress_collective)
             if tcfg.offload_master:
-                new_opt = zero1.offload_opt(new_opt, mesh)
+                new_opt = zero1.offload_opt(new_opt, mesh, z1spec)
         else:
             new_params, new_opt, om = opt_update(params, grads, opt_state)
 
@@ -185,9 +179,10 @@ def build_train_step(cfg: ArchConfig, mesh, tcfg: TrainConfig = TrainConfig()):
             new_state["ef"] = new_ef
         metrics = {"loss": loss, **om}
         if tcfg.local_grads and mesh is not None:
-            # wire bytes ONE shard contributes to the DP grad reduce (static)
-            metrics["dp_psum_bytes"] = compression.psum_bytes(
-                grads, compressed=dp_compress)
+            # wire bytes ONE shard contributes to the DP grad reduce (static;
+            # float32 — at published widths the count exceeds int32)
+            metrics["dp_psum_bytes"] = jnp.float32(compression.psum_bytes(
+                grads, compressed=dp_compress))
         return new_state, metrics
 
     return train_step
@@ -202,12 +197,8 @@ def make_state_shapes(cfg: ArchConfig, tcfg: TrainConfig, mesh=None):
         params = tr.init_params(cfg, jax.random.PRNGKey(0))
         state = {"params": params, "prof": neoprof_init(prof_params)}
         if tcfg.zero1:
-            # zero1 state built separately (needs mesh) — placeholder zeros
-            state["opt"] = {"m": jnp.zeros((1,), jnp.float32),
-                            "v": jnp.zeros((1,), jnp.float32),
-                            "step": jnp.zeros((), jnp.int32)}
-            if tcfg.compress_collective:
-                state["opt"]["ef"] = jnp.zeros((1,), jnp.float32)
+            state["opt"], _ = zero1.zero1_init(
+                params, None, compress_collective=tcfg.compress_collective)
         else:
             state["opt"] = opt_init(params)
         if tcfg.grad_compression:
@@ -222,8 +213,6 @@ def state_shardings(state_shapes, mesh, fsdp: bool = False):
     pspecs = param_pspecs(state_shapes["params"], mesh, fsdp=fsdp)
 
     def opt_specs(o):
-        if isinstance(o, dict) and "m" in o and isinstance(o["m"], dict):
-            return {"m": pspecs, "v": pspecs, "step": P()}      # AdamW
         if isinstance(o, dict) and "s" in o:                     # Adafactor
             def fact(shape_struct, ps):
                 parts = tuple(ps)
@@ -236,12 +225,9 @@ def state_shardings(state_shapes, mesh, fsdp: bool = False):
                 fact, state_shapes["params"], pspecs,
                 is_leaf=lambda x: hasattr(x, "shape") or isinstance(x, P))
             return {"s": s_specs, "step": P()}
-        # zero1: flat fp32 vectors sharded over every mesh axis
-        def leaf(kp, l):
-            if l.ndim == 1 and l.shape[0] > 1 << 16:
-                return P(tuple(mesh.axis_names))
-            return P(*([None] * l.ndim))
-        return jax.tree_util.tree_map_with_path(leaf, o)
+        # AdamW, and ZeRO-1's m/v/ef trees (placed like the params here;
+        # zero1_update constrains them to its own shards)
+        return {k: (P() if k == "step" else pspecs) for k in o}
 
     specs = {
         "params": pspecs,

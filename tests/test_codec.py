@@ -187,10 +187,10 @@ def test_copy_rows_preserves_wire_format():
     state = mem.init()
     src, dst = np.array([4, 7]), np.array([30, 31])
     mem.copy_rows(state, src, dst)
-    np.testing.assert_array_equal(np.asarray(mem.buffers.slow[dst]),
-                                  np.asarray(mem.buffers.slow[src]))
-    np.testing.assert_array_equal(np.asarray(mem.buffers.scale[dst]),
-                                  np.asarray(mem.buffers.scale[src]))
+    np.testing.assert_array_equal(np.asarray(mem.buffers.slow)[dst],
+                                  np.asarray(mem.buffers.slow)[src])
+    np.testing.assert_array_equal(np.asarray(mem.buffers.scale)[dst],
+                                  np.asarray(mem.buffers.scale)[src])
     np.testing.assert_array_equal(
         np.asarray(mem.read_rows(state, dst)),
         np.asarray(mem.read_rows(state, src)))
@@ -243,7 +243,8 @@ def test_zero1_compressed_collective_parity_and_bytes():
               "b": jnp.asarray(rng.normal(size=(48,)), jnp.float32)}
     st_f, spec = zero1.zero1_init(params, None)
     st_c, _ = zero1.zero1_init(params, None, compress_collective=True)
-    assert "ef" in st_c and st_c["ef"].shape == (spec.padded,)
+    assert "ef" in st_c and jax.tree.map(jnp.shape, st_c["ef"]) == \
+        jax.tree.map(jnp.shape, params)
     pf, pc = params, params
     for _ in range(5):
         grads = jax.tree.map(
@@ -253,11 +254,12 @@ def test_zero1_compressed_collective_parity_and_bytes():
         pc, st_c, om_c = zero1.zero1_update(cfg, pc, grads, st_c, spec, None,
                                             compress_collective=True)
     # m/v/step never see the codec — quantization is strictly post-update
-    np.testing.assert_array_equal(np.asarray(st_f["m"]), np.asarray(st_c["m"]))
-    np.testing.assert_array_equal(np.asarray(st_f["v"]), np.asarray(st_c["v"]))
+    for k in ("m", "v"):
+        for a, b in zip(jax.tree.leaves(st_f[k]), jax.tree.leaves(st_c[k])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     drift = max(float(jnp.max(jnp.abs(pf[k] - pc[k]))) for k in params)
     assert drift <= 1e-3
-    assert om_f["collective_bytes"] == 4 * spec.padded
+    assert om_f["collective_bytes"] == 4 * spec.size
     assert om_c["collective_bytes"] / om_f["collective_bytes"] <= 0.30
 
 
@@ -269,4 +271,5 @@ def test_zero1_toggle_off_threads_ef_through():
                     total_steps=10)
     _, st2, _ = zero1.zero1_update(cfg, params, grads, st, spec, None,
                                    compress_collective=False)
-    np.testing.assert_array_equal(np.asarray(st2["ef"]), np.asarray(st["ef"]))
+    np.testing.assert_array_equal(np.asarray(st2["ef"]["w"]),
+                                  np.asarray(st["ef"]["w"]))

@@ -81,10 +81,12 @@ def test_compressed_bytes_counts_payload():
 # sharding: inference + divisibility fallback (AbstractMesh: no devices)
 # ---------------------------------------------------------------------------
 
-MESH24 = AbstractMesh((("data", 2), ("model", 4)))
+@pytest.fixture
+def mesh24():
+    return AbstractMesh((2, 4), ("data", "model"))
 
 
-def test_param_pspecs_rules():
+def test_param_pspecs_rules(mesh24):
     params = {
         "embed": {"table": jax.ShapeDtypeStruct((256, 64), jnp.bfloat16)},
         "blocks": {
@@ -96,7 +98,7 @@ def test_param_pspecs_rules():
                     "router": jax.ShapeDtypeStruct((64, 8), jnp.float32)},
         },
     }
-    sp = param_pspecs(params, MESH24)
+    sp = param_pspecs(params, mesh24)
     assert sp["embed"]["table"] == P("model", None)
     assert sp["blocks"]["ln1"]["scale"] == P(None, None)       # norm: replicated
     assert sp["blocks"]["attn"]["wq"] == P(None, None, "model")  # column
@@ -106,48 +108,48 @@ def test_param_pspecs_rules():
     assert sp["blocks"]["ffn"]["router"] == P(None, None)      # replicated
 
 
-def test_param_pspecs_moe_expert_dim():
+def test_param_pspecs_moe_expert_dim(mesh24):
     p = {"blocks": {"ffn": {
         "w_gate": jax.ShapeDtypeStruct((2, 8, 32, 64), jnp.bfloat16),
         "w_out": jax.ShapeDtypeStruct((2, 8, 64, 32), jnp.bfloat16),
     }}}
-    sp = param_pspecs(p, MESH24)
+    sp = param_pspecs(p, mesh24)
     assert sp["blocks"]["ffn"]["w_gate"] == P(None, "model", None, None)
     assert sp["blocks"]["ffn"]["w_out"] == P(None, "model", None, None)
 
 
-def test_param_pspecs_fallback_to_replicated():
+def test_param_pspecs_fallback_to_replicated(mesh24):
     """A dim that doesn't divide the mesh axis must stay unsharded."""
     p = {"w_in": jax.ShapeDtypeStruct((10, 6), jnp.float32),    # 6 % 4 != 0
          "table": jax.ShapeDtypeStruct((7, 64), jnp.float32),   # 7 % 4 != 0
          "tiny": jax.ShapeDtypeStruct((3, 2), jnp.float32)}
-    sp = param_pspecs(p, MESH24)
+    sp = param_pspecs(p, mesh24)
     assert sp["w_in"] == P(None, None)
     assert sp["table"] == P(None, None)
     assert sp["tiny"] == P(None, None)
 
 
-def test_param_pspecs_fsdp_adds_data_axis():
+def test_param_pspecs_fsdp_adds_data_axis(mesh24):
     p = {"w_in": jax.ShapeDtypeStruct((64, 128), jnp.float32)}
-    sp = param_pspecs(p, MESH24, fsdp=True)
+    sp = param_pspecs(p, mesh24, fsdp=True)
     assert sp["w_in"] == P("data", "model")
     # fallback: nothing left to shard over data -> column sharding only
     q = {"w_in": jax.ShapeDtypeStruct((3, 128), jnp.float32)}
-    assert param_pspecs(q, MESH24, fsdp=True)["w_in"] == P(None, "model")
+    assert param_pspecs(q, mesh24, fsdp=True)["w_in"] == P(None, "model")
 
 
-def test_batch_and_cache_pspecs():
-    assert batch_pspec(MESH24) == P(("data",), None)
+def test_batch_and_cache_pspecs(mesh24):
+    assert batch_pspec(mesh24) == P(("data",), None)
     cache = {"blocks": {
         "k": jax.ShapeDtypeStruct((4, 2, 32, 2, 16), jnp.bfloat16),
         "pos": jax.ShapeDtypeStruct((), jnp.int32),
     }}
-    sp = cache_pspecs(cache, MESH24)
+    sp = cache_pspecs(cache, mesh24)
     assert sp["blocks"]["k"] == P(None, ("data",), "model", None, None)
     assert sp["blocks"]["pos"] == P()
     paged = {"blocks": {
         "k_pages": jax.ShapeDtypeStruct((4, 2, 16, 8, 2, 16), jnp.bfloat16)}}
-    sp = cache_pspecs(paged, MESH24, slot_axes=("data", "model"))
+    sp = cache_pspecs(paged, mesh24, slot_axes=("data", "model"))
     assert sp["blocks"]["k_pages"] == P(None, None, ("data", "model"),
                                         None, None, None)
 
@@ -270,5 +272,6 @@ def test_train_step_zero1_compressed_collective_end_to_end():
         losses.append(float(metrics["loss"]))
     assert np.isfinite(losses).all(), losses
     assert losses[-1] < losses[0]
-    assert float(jnp.sum(jnp.abs(state["opt"]["ef"]))) > 0.0
-    assert int(metrics["collective_bytes"]) < 4 * spec.padded
+    assert sum(float(jnp.sum(jnp.abs(e)))
+               for e in jax.tree.leaves(state["opt"]["ef"])) > 0.0
+    assert int(metrics["collective_bytes"]) < 4 * spec.size

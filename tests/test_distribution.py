@@ -18,7 +18,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _run_subprocess(code: str):
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=SRC)
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -45,7 +45,7 @@ def test_grad_compression_error_feedback():
 
 
 def test_zero1_matches_adamw():
-    """Flat-sharded ZeRO-1 update == per-tensor AdamW (single device)."""
+    """ZeRO-1 update == per-tensor AdamW (single device)."""
     cfg = OptConfig(lr=1e-2, weight_decay=0.0, warmup_steps=0, total_steps=100)
     params = {"a": jnp.ones((4, 8), jnp.float32) * 0.5,
               "b": jnp.arange(6, dtype=jnp.float32)}
@@ -54,10 +54,7 @@ def test_zero1_matches_adamw():
     st_ref = adamw_init(params)
     p_ref, st_ref, _ = adamw_update(cfg, params, grads, st_ref)
 
-    spec = zero1.flat_spec(params, n_shards=1)
-    st_z = {"m": jnp.zeros((spec.padded,), jnp.float32),
-            "v": jnp.zeros((spec.padded,), jnp.float32),
-            "step": jnp.zeros((), jnp.int32)}
+    st_z, spec = zero1.zero1_init(params, None)
     p_z, st_z, _ = zero1.zero1_update(cfg, params, grads, st_z, spec, None)
     for k in params:
         np.testing.assert_allclose(np.asarray(p_ref[k]), np.asarray(p_z[k]),
@@ -74,9 +71,10 @@ def test_sharded_train_step_runs_and_matches_single():
         from repro.configs.registry import get_smoke_config
         from repro.models import transformer as tr
         from repro.dist.sharding import param_pspecs
+        from repro.launch.mesh import make_mesh
 
         cfg = get_smoke_config('llama3.2-3b')
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         params = tr.init_params(cfg, jax.random.PRNGKey(0))
         tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab)
         batch = {'tokens': tokens, 'labels': tokens}
@@ -156,7 +154,6 @@ def test_sharded_flash_decode_combine():
     """paged attention sharded over slots == unsharded (combine correctness)."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.kernels.paged_attn import ops as pa
         mesh = jax.make_mesh((8,), ('s',))
@@ -174,9 +171,9 @@ def test_sharded_flash_decode_combine():
             return pa.combine_stats(m, l, acc, ('s',)).astype(q.dtype)
 
         with mesh:
-            o = jax.jit(shard_map(body, mesh=mesh,
+            o = jax.jit(jax.shard_map(body, mesh=mesh,
                 in_specs=(P(), P(None, 's'), P(None, 's'), P(None, 's')),
-                out_specs=P(), check_rep=False))(q, kp, vp, lens)
+                out_specs=P(), check_vma=False))(q, kp, vp, lens)
         err = float(jnp.max(jnp.abs(o - o_ref)))
         assert err < 1e-4, err
         print('OK', err)
@@ -192,7 +189,6 @@ def test_sharded_flash_decode_page_mass_combine():
     own pair — DESIGN.md §10)."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.kernels.paged_attn import ops as pa
         from repro.kernels.paged_attn.ref import page_mass_ref
@@ -214,9 +210,9 @@ def test_sharded_flash_decode_page_mass_combine():
             return o.astype(q.dtype), mass
 
         with mesh:
-            o, mass = jax.jit(shard_map(body, mesh=mesh,
+            o, mass = jax.jit(jax.shard_map(body, mesh=mesh,
                 in_specs=(P(), P(None, 's'), P(None, 's'), P(None, 's')),
-                out_specs=(P(), P(None, 's')), check_rep=False))(q, kp, vp, lens)
+                out_specs=(P(), P(None, 's')), check_vma=False))(q, kp, vp, lens)
         err_o = float(jnp.max(jnp.abs(o - o_ref)))
         err_m = float(jnp.max(jnp.abs(mass - mass_ref)))
         err_r = float(jnp.max(jnp.abs(mass - page_mass_ref(q, kp, lens))))
@@ -238,10 +234,11 @@ def test_sharded_paged_decode_mass_stream():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.registry import get_smoke_config
+        from repro.launch.mesh import make_mesh
         from repro.models import transformer as tr, decode as dec
         cfg = get_smoke_config('llama3.2-3b')
         params = tr.init_params(cfg, jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((8,), ('s',))
+        mesh = make_mesh((8,), ('s',))
         smesh = {'mesh': mesh, 'axes': ('s',)}
         tok = jnp.zeros((2, 1), jnp.int32)
         cl = dec.init_paged_cache(cfg, 2, 8, 4)
@@ -271,7 +268,8 @@ def test_sharded_paged_decode_mass_stream():
 
 
 def test_host_offload_fallback():
-    """CPU backend: slow-tier placement degrades to logical separation."""
+    """Slow-tier placement is real pinned host memory on this backend too,
+    and the round trip through it is value-exact."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -280,8 +278,10 @@ def test_host_offload_fallback():
     x = jnp.arange(8.0)
     y = ho.to_slow_tier(x, mesh, P(None))
     z = ho.to_fast_tier(y, mesh, P(None))
+    assert y.sharding.memory_kind == ho.SLOW_KIND
+    assert z.sharding.memory_kind == "device"
     assert float(jnp.sum(z - x)) == 0.0
-    assert isinstance(ho.supports_memory_kinds(), bool)
+    assert ho.supports_memory_kinds()
 
 
 @pytest.mark.slow
@@ -296,10 +296,11 @@ def test_local_grads_compressed_psum_parity():
         from repro.dist import compression
         from repro.models import transformer as tr
         from repro.optim.optimizers import OptConfig, make_optimizer
+        from repro.launch.mesh import make_mesh
         from repro.train.step import TrainConfig, build_train_step
 
         cfg = get_smoke_config('llama3.2-3b')
-        mesh = jax.make_mesh((4,), ('data',))
+        mesh = make_mesh((4,), ('data',))
         params = tr.init_params(cfg, jax.random.PRNGKey(0))
         tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                                     cfg.vocab)
@@ -357,10 +358,11 @@ def test_zero1_offload_master_parity():
         from repro.models import transformer as tr
         from repro.optim import zero1
         from repro.optim.optimizers import OptConfig
+        from repro.launch.mesh import make_mesh
         from repro.train.step import TrainConfig, build_train_step
 
         cfg = get_smoke_config('llama3.2-3b')
-        mesh = jax.make_mesh((8,), ('data',))
+        mesh = make_mesh((8,), ('data',))
         params = tr.init_params(cfg, jax.random.PRNGKey(0))
         tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                                     cfg.vocab)
@@ -387,8 +389,9 @@ def test_zero1_offload_master_parity():
         l_off, st_off = run(True)
         assert l_res == l_off, (l_res, l_off)
         for k in ('m', 'v'):
-            np.testing.assert_array_equal(np.asarray(st_res['opt'][k]),
-                                          np.asarray(st_off['opt'][k]))
+            for a, b in zip(jax.tree.leaves(st_res['opt'][k]),
+                            jax.tree.leaves(st_off['opt'][k])):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         print('OK', l_off[-1])
     """)
     assert "OK" in out

@@ -193,11 +193,12 @@ def test_injit_embedding_reads_bit_exact():
     so residency can only change WHERE a row is read, never its value."""
     prompt = (np.arange(2 * 10).reshape(2, 10) * 5) % 256
     _, on = _engine("llama3.2-3b", **KW)
-    out_on = on.generate(prompt, n_tokens=8)
+    out_on = on.generate(prompt, n_tokens=16)
     _, off = _engine("llama3.2-3b", **KW, jit_tier_reads=False)
-    out_off = off.generate(prompt, n_tokens=8)
+    out_off = off.generate(prompt, n_tokens=16)
     np.testing.assert_array_equal(out_on, out_off)
-    # the in-jit path really served through the tier (placement live)
+    # the in-jit path really served through the tier (placement live); 16
+    # tokens leave room for a re-read after the decode-time promotions
     assert on.daemon["embeddings"].hit_rate() > 0
 
 

@@ -2,7 +2,7 @@
 
 Covers the ISSUE-3 acceptance surface: bit-exact fast-tier serving after
 promotion, demotion write-back round-trips, byte metering that respects the
-per-epoch quota, the CPU logical-split fallback (this CI), the legacy shim
+per-epoch quota, the pinned-host slow store, the legacy shim
 forwarding + deprecation warnings, and the BENCH_serve.json schema checker.
 """
 import warnings
@@ -103,17 +103,61 @@ def test_epoch_bytes_never_exceed_quota_under_pressure():
     assert event is None and stats.last_epoch_bytes == 0
 
 
-def test_cpu_fallback_is_logical_split():
-    """On backends without memory kinds (this CI) the slow store is a plain
-    device array — the data path runs unchanged, placement is bookkeeping."""
-    assert not ho.supports_memory_kinds()   # CPU backend in CI
-    buffers = migrate_lib.init_buffers(_rows(8, (2,)), num_slots=2)
+def test_slow_store_lives_in_pinned_host():
+    """The slow store (and the int8 codec's scales) is a real pinned-host
+    array, and stays there through an epoch copy with a demotion
+    write-back and through a write verb — the verbs gather and scatter in
+    host memory instead of pulling the store onto the device."""
+    assert ho.supports_memory_kinds()
+    buffers = migrate_lib.init_buffers(_rows(8, (2,)), num_slots=2,
+                                       codec="int8")
     assert buffers.fast.shape == (2, 2) and buffers.slow.shape == (8, 2)
+    for store in (buffers.slow, buffers.scale):
+        assert store.sharding.memory_kind == ho.SLOW_KIND
     out, n_up, n_down = migrate_lib.migrate(
-        buffers, jnp.array([4, -1]), jnp.array([0, -1]), jnp.array([-1, -1]))
+        buffers, jnp.array([4, -1]), jnp.array([0, -1]), jnp.array([-1, -1]),
+        codec="int8")
     assert (n_up, n_down) == (1, 0)
-    np.testing.assert_array_equal(np.asarray(out.fast[0]),
-                                  np.asarray(buffers.slow[4]))
+    np.testing.assert_array_equal(
+        np.asarray(out.fast[0]),
+        np.asarray(migrate_lib.gather_rows(buffers, np.array([4])))[0])
+    out, n_up, n_down = migrate_lib.migrate(
+        out, jnp.array([5, -1]), jnp.array([0, -1]), jnp.array([4, -1]),
+        codec="int8")
+    assert (n_up, n_down) == (1, 1)
+    out = migrate_lib.write_rows(out, jnp.array([1, -1]), jnp.array([-1, -1]),
+                                 jnp.full((2, 2), 7.0), codec="int8")
+    for store in (out.slow, out.scale):
+        assert store.sharding.memory_kind == ho.SLOW_KIND
+    np.testing.assert_allclose(
+        np.asarray(migrate_lib.gather_rows(out, np.array([1])))[0],
+        np.full(2, 7.0), rtol=1e-2)
+
+
+@pytest.mark.parametrize("row_shape", [(), (3,), (2, 3)],
+                         ids=["scale", "flat", "tile"])
+def test_host_verbs_match_device_indexing(row_shape):
+    """host_take / host_put over a pinned-host store equal plain indexing
+    of a device copy, dropped lanes (-1, out of range) and repeated ids
+    included.  Rows of two or more dims take the per-row DMA form the
+    chip runs, narrower ones the host scatter; a batch with no lane in
+    range leaves the store as it was."""
+    import jax
+    data = _rows(8, row_shape)
+    store = migrate_lib.place_slow(data)
+    idx = jnp.array([5, -1, 2, 8, 5, 0, -1], jnp.int32)
+    rows = -1.0 - _rows(7, row_shape)
+    got = jax.jit(ho.host_take)(store, jnp.array([[7, 0], [3, 3]], jnp.int32))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(data)[[[7, 0], [3, 3]]])
+    out = ho.rehost(jax.jit(ho.host_put)(store, idx, rows), store.sharding)
+    assert out.sharding.memory_kind == ho.SLOW_KIND
+    want = jnp.asarray(data).at[jnp.where(idx < 0, 8, idx)].set(
+        rows, mode="drop")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    untouched = jax.jit(ho.host_put)(store, jnp.array([-1, 8], jnp.int32),
+                                     rows[:2])
+    np.testing.assert_array_equal(np.asarray(untouched), np.asarray(data))
 
 
 def test_bind_data_validates_geometry_against_spec():
