@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.core import sketch as sk
 from repro.core.sketch import SketchParams, SketchState
+from repro.spans import pull
 
 
 class NeoProfParams(NamedTuple):
@@ -153,8 +154,8 @@ class NeoProfCommands:
         return int(state.hot_count)
 
     def get_hotpages(self, state: NeoProfState) -> jnp.ndarray:    # 0x400 (seq.)
-        n = int(state.hot_count)
-        return jax.device_get(state.hot_buf)[:n]
+        n = int(pull(state.hot_count, "hot_pages"))
+        return pull(state.hot_buf, "hot_pages")[:n]
 
     def drain_hotpages(self, state: NeoProfState) -> tuple[NeoProfState, jnp.ndarray]:
         pages = self.get_hotpages(state)
@@ -175,12 +176,15 @@ class NeoProfCommands:
 
     def bandwidth_util(self, state: NeoProfState) -> float:
         m = state.monitor
-        return float((m.rd_bytes + m.wr_bytes) / jnp.maximum(m.total_budget, 1.0))
+        return float(pull((m.rd_bytes + m.wr_bytes)
+                          / jnp.maximum(m.total_budget, 1.0), "monitor"))
 
     # -- histogram unit ------------------------------------------------------
     def get_hist(self, state: NeoProfState) -> jnp.ndarray:        # 0x800-0xA00
-        return jax.device_get(sk.sketch_histogram(state.sketch, self.params.sketch))
+        return pull(sk.sketch_histogram(state.sketch, self.params.sketch),
+                    "hist")
 
     def get_error_bound(self, state: NeoProfState, hist=None) -> int:
         h = self.get_hist(state) if hist is None else hist
-        return int(sk.error_bound_from_hist(h, self.params.sketch, self.params.delta))
+        return int(pull(sk.error_bound_from_hist(h, self.params.sketch,
+                                                 self.params.delta), "hist"))

@@ -54,7 +54,6 @@ benchmarks; the dry-run lowers the same step functions at production shapes.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +65,7 @@ from repro.configs.base import ArchConfig
 from repro.models import decode as dec
 from repro.models import transformer as tr
 from repro.serve.clock import TickClock
+from repro.spans import pull, span
 
 
 @dataclasses.dataclass
@@ -409,7 +409,7 @@ class ServeEngine:
             logits = None
             for off in range(0, s, cap):
                 logits = self._prefill_chunk(jnp.asarray(tokens[:, off:off + cap]))
-            return np.asarray(jnp.argmax(logits, -1))
+            return pull(jnp.argmax(logits, -1), "logits")
         # dense path: ONE scan fills the cache and yields the last-token
         # logits together — the prompt runs exactly once, and the tiering
         # streams are replayed as one masked observation batch
@@ -420,7 +420,7 @@ class ServeEngine:
             self._tier_reads())
         self._observe_prefill(tokens, streams)
         self._maybe_tick(s)
-        return np.asarray(jnp.argmax(logits, -1))
+        return pull(jnp.argmax(logits, -1), "logits")
 
     def _prefill_chunk(self, tok: jax.Array):
         """One single-request paged prefill chunk: scan-advance the cache,
@@ -429,18 +429,20 @@ class ServeEngine:
         n = tok.shape[1]
         logits, self.cache, streams = self._prefill_paged_jit(
             self.params, self.cache, tok, None, None, self._tier_reads())
-        self._observe_prefill(np.asarray(tok), streams)
+        self._observe_prefill(pull(tok, "tokens"), streams)
         if "kv" in self.daemon:
-            mass, ids = self._kv_page_stream()
-            km = streams.get("kv_mass")
-            if self._kernel_mass and km is not None:
-                # chunk-summed kernel mass over the post-chunk window: the
-                # (C, G, n_attn, B, S) stream head-averaged over groups,
-                # positions and lockstep batch rows, summed over the chunk —
-                # the aggregate of the per-step NeoProf streams (DESIGN.md §10)
-                mass = jnp.sum(jnp.mean(km, axis=(1, 2, 3)), axis=0)
-            if ids.size:
-                self.daemon.observe("kv", mass, ids)
+            with span("tier/observe", resource="kv"):
+                mass, ids = self._kv_page_stream()
+                km = streams.get("kv_mass")
+                if self._kernel_mass and km is not None:
+                    # chunk-summed kernel mass over the post-chunk window:
+                    # the (C, G, n_attn, B, S) stream head-averaged over
+                    # groups, positions and lockstep batch rows, summed over
+                    # the chunk — the aggregate of the per-step NeoProf
+                    # streams (DESIGN.md §10)
+                    mass = jnp.sum(jnp.mean(km, axis=(1, 2, 3)), axis=0)
+                if ids.size:
+                    self.daemon.observe("kv", mass, ids)
         self._flush_kv_slow()
         self._maybe_tick(n)
         return logits
@@ -449,13 +451,16 @@ class ServeEngine:
         """Replay a prefilled chunk's embedding/expert streams as ONE
         observation batch each (not one per prompt token)."""
         if "embeddings" in self.daemon:
-            self.daemon.observe("embeddings", jnp.asarray(tokens, jnp.int32))
+            with span("tier/observe", resource="embeddings"):
+                self.daemon.observe("embeddings",
+                                    jnp.asarray(tokens, jnp.int32))
         if "experts" in self.daemon and streams.get("router") is not None:
-            self.daemon.observe("experts", streams["router"])
+            with span("tier/observe", resource="experts"):
+                self.daemon.observe("experts", streams["router"])
 
     def step(self, token: np.ndarray) -> np.ndarray:
         logits = self._advance(jnp.asarray(token)[:, None])
-        return np.asarray(jnp.argmax(logits[:, -1], -1))
+        return pull(jnp.argmax(logits[:, -1], -1), "logits")
 
     def generate(self, prompt: np.ndarray, n_tokens: int,
                  aux_embeds=None) -> np.ndarray:
@@ -511,23 +516,25 @@ class ServeEngine:
             raise ValueError("advance_lanes requires ServeConfig.lanes > 0")
         if self.cache is None:
             self.start_lanes()
-        t0 = time.perf_counter()
-        self._lane_active = np.asarray(active, bool).copy()
-        self._lane_segments = np.asarray(segments, np.int32).copy()
-        tokens = np.asarray(tokens, np.int32)
-        tok = jnp.asarray(tokens)[:, None]
-        out = self._decode_paged(self.params, self.cache, tok,
-                                 self._tier_reads(),
-                                 jnp.asarray(self._lane_active))
-        if self._want_streams:
-            logits, self.cache, streams = out
-        else:
-            (logits, self.cache), streams = out, {}
-        self._set_kv_mass(streams)
-        self._observe_lanes(tokens, streams)
-        self._maybe_tick()
-        out_logits = np.asarray(logits[:, -1])   # host sync = the step's end
-        self._decode_s += time.perf_counter() - t0
+        with span("engine/advance") as sp:
+            self._lane_active = np.asarray(active, bool).copy()
+            self._lane_segments = np.asarray(segments, np.int32).copy()
+            tokens = np.asarray(tokens, np.int32)
+            tok = jnp.asarray(tokens)[:, None]
+            with span("engine/dispatch"):
+                out = self._decode_paged(self.params, self.cache, tok,
+                                         self._tier_reads(),
+                                         jnp.asarray(self._lane_active))
+            if self._want_streams:
+                logits, self.cache, streams = out
+            else:
+                (logits, self.cache), streams = out, {}
+            self._set_kv_mass(streams)
+            self._observe_lanes(tokens, streams)
+            self._maybe_tick()
+            # host sync = the step's end
+            out_logits = pull(logits[:, -1], "logits")
+        self._decode_s += sp.elapsed
         return out_logits
 
     def prefill_lane(self, lane: int, tokens, segment: int,
@@ -570,19 +577,20 @@ class ServeEngine:
         """One lane-chunk scan: ragged pieces are padded to the fixed chunk
         width with valid=False no-op steps (one traced shape per chunk
         size), so a prompt tail never retraces the scan."""
-        n = piece.size
-        tok = np.zeros((self.scfg.lanes, chunk), np.int32)
-        tok[lane, :n] = piece
-        valid = np.zeros((self.scfg.lanes, chunk), bool)
-        valid[lane, :n] = True
-        logits, self.cache, streams = self._prefill_paged_jit(
-            self.params, self.cache, jnp.asarray(tok), jnp.asarray(valid),
-            jnp.asarray(active), self._tier_reads())
-        self._lane_active = active.copy()
-        self._observe_lane_chunk(lane, tok, valid, streams, active)
-        self._flush_kv_lanes(lanes=[lane])
-        self._maybe_tick(n)
-        return np.asarray(logits[lane])
+        with span("engine/prefill_chunk"):
+            n = piece.size
+            tok = np.zeros((self.scfg.lanes, chunk), np.int32)
+            tok[lane, :n] = piece
+            valid = np.zeros((self.scfg.lanes, chunk), bool)
+            valid[lane, :n] = True
+            logits, self.cache, streams = self._prefill_paged_jit(
+                self.params, self.cache, jnp.asarray(tok), jnp.asarray(valid),
+                jnp.asarray(active), self._tier_reads())
+            self._lane_active = active.copy()
+            self._observe_lane_chunk(lane, tok, valid, streams, active)
+            self._flush_kv_lanes(lanes=[lane])
+            self._maybe_tick(n)
+            return pull(logits[lane], "logits")
 
     def _observe_lane_chunk(self, lane: int, tok: np.ndarray,
                             valid: np.ndarray, streams: dict,
@@ -590,48 +598,60 @@ class ServeEngine:
         """Feed one chunk's tiering streams in ONE observation batch per
         resource, other lanes (and tail padding) masked to -1."""
         if "embeddings" in self.daemon:
-            self.daemon.observe(
-                "embeddings", jnp.asarray(np.where(valid, tok, -1), jnp.int32))
+            with span("tier/observe", resource="embeddings"):
+                self.daemon.observe("embeddings", jnp.asarray(
+                    np.where(valid, tok, -1), jnp.int32))
         if "experts" in self.daemon and streams.get("router") is not None:
-            router = streams["router"]      # (C, G, n_moe, L, 1, k)
-            mask = jnp.asarray(valid.T)[:, None, None, :, None, None]
-            self.daemon.observe("experts", jnp.where(mask, router, -1))
+            with span("tier/observe", resource="experts"):
+                router = streams["router"]      # (C, G, n_moe, L, 1, k)
+                mask = jnp.asarray(valid.T)[:, None, None, :, None, None]
+                self.daemon.observe("experts", jnp.where(mask, router, -1))
         if "kv" in self.daemon:
-            sv = self._kv_lane_stream(active=active)
-            if sv is None:
-                return
-            mass, gids = sv                 # (L, S) post-chunk window
-            km = streams.get("kv_mass")
-            if self._kernel_mass and km is not None:
-                # per-step (C, G, n_attn, L, S) kernel mass: head-averaged,
-                # summed over the chunk's valid steps — the bulk analogue of
-                # the one-step stream advance_lanes feeds
-                per_step = jnp.mean(km, axis=(1, 2))          # (C, L, S)
-                agg = jnp.sum(per_step * jnp.asarray(valid.T)[:, :, None],
-                              axis=0)                         # (L, S)
-                mass = np.where(gids >= 0, np.asarray(agg, np.float32), 0.0)
-            self._count_shared_mass(mass, gids)
-            self.daemon.observe("kv", jnp.asarray(mass.reshape(-1)),
-                                jnp.asarray(gids.reshape(-1), jnp.int32))
+            with span("tier/observe", resource="kv"):
+                sv = self._kv_lane_stream(active=active)
+                if sv is None:
+                    return
+                mass, gids = sv                 # (L, S) post-chunk window
+                km = streams.get("kv_mass")
+                if self._kernel_mass and km is not None:
+                    # per-step (C, G, n_attn, L, S) kernel mass:
+                    # head-averaged, summed over the chunk's valid steps —
+                    # the bulk analogue of the one-step stream
+                    # advance_lanes feeds
+                    per_step = jnp.mean(km, axis=(1, 2))      # (C, L, S)
+                    agg = jnp.sum(
+                        per_step * jnp.asarray(valid.T)[:, :, None],
+                        axis=0)                               # (L, S)
+                    mass = np.where(gids >= 0, np.asarray(
+                        pull(agg, "kv_mass"), np.float32), 0.0)
+                self._count_shared_mass(mass, gids)
+                self.daemon.observe("kv", jnp.asarray(mass.reshape(-1)),
+                                    jnp.asarray(gids.reshape(-1), jnp.int32))
 
     def _observe_lanes(self, tokens: np.ndarray, streams: dict) -> None:
         """Feed the tiering streams with inactive lanes masked to -1 pads."""
         act = self._lane_active
         if "embeddings" in self.daemon:
-            toks = np.where(act, tokens, -1)
-            self.daemon.observe("embeddings", jnp.asarray(toks, jnp.int32))
+            with span("tier/observe", resource="embeddings"):
+                toks = np.where(act, tokens, -1)
+                self.daemon.observe("embeddings",
+                                    jnp.asarray(toks, jnp.int32))
         if "experts" in self.daemon and streams.get("router") is not None:
-            router = streams["router"]        # (G, n_moe, L, 1, k)
-            mask = jnp.asarray(act)[None, None, :, None, None]
-            self.daemon.observe("experts", jnp.where(mask, router, -1))
+            with span("tier/observe", resource="experts"):
+                router = streams["router"]        # (G, n_moe, L, 1, k)
+                mask = jnp.asarray(act)[None, None, :, None, None]
+                self.daemon.observe("experts", jnp.where(mask, router, -1))
         if "kv" in self.daemon:
-            sv = self._kv_lane_stream()
-            if sv is not None:
+            with span("tier/observe", resource="kv"):
+                sv = self._kv_lane_stream()
+                if sv is None:
+                    return
                 mass, gids = sv
                 if self._kernel_mass and self._last_kv_mass is not None:
                     # per-lane kernel mass, masked to the live lanes'
                     # segment-mapped pages (same mask the gids carry)
-                    km = np.asarray(self._last_kv_mass, np.float32)
+                    km = np.asarray(pull(self._last_kv_mass, "kv_mass"),
+                                    np.float32)
                     mass = np.where(gids >= 0, km, 0.0)
                 self._count_shared_mass(mass, gids)
                 self.daemon.observe("kv", jnp.asarray(mass.reshape(-1)),
@@ -670,7 +690,7 @@ class ServeEngine:
         the dense prologue ring) is snapshotted host-side into the returned
         residual.  :meth:`resume_lane` restores bit-exactly."""
         self._flush_kv_lanes(lanes=[lane], force=True)
-        residual = {"pos": int(np.asarray(self.cache["pos"])[lane]),
+        residual = {"pos": int(pull(self.cache["pos"], "lane_state")[lane]),
                     "segment": int(self._lane_segments[lane]),
                     # page-table row + publish witnesses travel with the
                     # request: its claim on shared pool pages survives the
@@ -685,11 +705,11 @@ class ServeEngine:
                 continue
             skip = ("k_pages", "v_pages") if entry is rep else ()
             residual["blocks"].append(
-                {k: np.asarray(v[:, lane]) for k, v in entry.items()
+                {k: pull(v[:, lane], "lane_state") for k, v in entry.items()
                  if k not in skip})
         for entry in self.cache.get("prologue", []):
             residual["prologue"].append(
-                {k: np.asarray(v[lane]) for k, v in entry.items()})
+                {k: pull(v[lane], "lane_state") for k, v in entry.items()})
         return residual
 
     def resume_lane(self, lane: int, residual: dict) -> int:
@@ -719,8 +739,8 @@ class ServeEngine:
         entry = self._paged_entry()
         if entry is None or segment < 0:
             return 0
-        plen = np.asarray(entry["page_len"])[0, lane][None]      # (1, S)
-        cur = np.asarray(entry["cur_slot"])[0, lane][None]       # (1,)
+        plen = pull(entry["page_len"], "ring_view")[0, lane][None]  # (1, S)
+        cur = pull(entry["cur_slot"], "ring_view")[0, lane][None]   # (1,)
         pos = np.asarray([residual["pos"]])
         local = self._ring_page_ids(plen, cur, pos, self.scfg.page_t)[0]
         slots = np.flatnonzero(local >= 0)
@@ -830,7 +850,7 @@ class ServeEngine:
         sel, gsel = locals_[-S:], gids[-S:]
         h = self.daemon["kv"]
         _, hit = h.lookup(jnp.asarray(gsel, jnp.int32))
-        fast_n = int(np.asarray(hit).sum())
+        fast_n = int(pull(hit, "reuse_hit").sum())
         rows = h.read_rows(jnp.asarray(gsel, jnp.int32))
         rows = jnp.moveaxis(rows, 0, 1)          # (G, n, T, hkv, dk+dv)
         new_pos = int(locals_[-1] + 1) * T
@@ -856,7 +876,7 @@ class ServeEngine:
         if self.reuse is None:
             return 0
         toks = np.asarray(tokens).ravel()
-        pos = int(np.asarray(self.cache["pos"])[lane])
+        pos = int(pull(self.cache["pos"], "lane_state")[lane])
         n_pages = min(toks.size, pos) // self.scfg.page_t
         if n_pages <= 0 or self._lane_segments[lane] < 0:
             return 0
@@ -909,21 +929,22 @@ class ServeEngine:
     def _advance(self, tok: jax.Array):
         """One decode step: run the jitted body, feed the tiering streams,
         tick the multiplexed daemon on its cadence."""
-        t0 = time.perf_counter()
-        if self.scfg.paged:
-            out = self._decode_paged(self.params, self.cache, tok,
-                                     self._tier_reads(), None)
-        else:
-            out = self._decode(self.params, self.cache, tok, self.aux,
-                               self._tier_reads())
-        if self._want_streams:
-            logits, self.cache, streams = out
-        else:
-            (logits, self.cache), streams = out, {}
-        self._set_kv_mass(streams)
-        self._observe(tok, streams)
-        self._maybe_tick()
-        self._decode_s += time.perf_counter() - t0
+        with span("engine/advance") as sp:
+            with span("engine/dispatch"):
+                if self.scfg.paged:
+                    out = self._decode_paged(self.params, self.cache, tok,
+                                             self._tier_reads(), None)
+                else:
+                    out = self._decode(self.params, self.cache, tok,
+                                       self.aux, self._tier_reads())
+            if self._want_streams:
+                logits, self.cache, streams = out
+            else:
+                (logits, self.cache), streams = out, {}
+            self._set_kv_mass(streams)
+            self._observe(tok, streams)
+            self._maybe_tick()
+        self._decode_s += sp.elapsed
         return logits
 
     def _set_kv_mass(self, streams: dict) -> None:
@@ -937,18 +958,21 @@ class ServeEngine:
 
     def _observe(self, tok: jax.Array, streams: dict) -> None:
         if "embeddings" in self.daemon:
-            self.daemon.observe("embeddings", tok)
+            with span("tier/observe", resource="embeddings"):
+                self.daemon.observe("embeddings", tok)
         if "experts" in self.daemon and streams.get("router") is not None:
-            self.daemon.observe("experts", streams["router"])
+            with span("tier/observe", resource="experts"):
+                self.daemon.observe("experts", streams["router"])
         if "kv" in self.daemon:
-            mass, ids = self._kv_page_stream()
-            if self._kernel_mass and self._last_kv_mass is not None:
-                # kernel-true hotness: batch rows advance in lockstep over
-                # the same page ids, so the row-mean is the device's
-                # aggregate view of the step's attention mass
-                mass = jnp.mean(self._last_kv_mass, axis=0)
-            if ids.size:
-                self.daemon.observe("kv", mass, ids)
+            with span("tier/observe", resource="kv"):
+                mass, ids = self._kv_page_stream()
+                if self._kernel_mass and self._last_kv_mass is not None:
+                    # kernel-true hotness: batch rows advance in lockstep
+                    # over the same page ids, so the row-mean is the
+                    # device's aggregate view of the step's attention mass
+                    mass = jnp.mean(self._last_kv_mass, axis=0)
+                if ids.size:
+                    self.daemon.observe("kv", mass, ids)
 
     def _paged_entry(self) -> dict | None:
         """The representative paged-attention cache entry (first in-pattern).
@@ -966,9 +990,10 @@ class ServeEngine:
         entry = self._paged_entry()
         if entry is None:
             return None
-        plen = np.asarray(entry["page_len"])[0]              # (B, S)
-        cur = np.asarray(entry["cur_slot"])[0]               # (B,)
-        pos = np.broadcast_to(np.asarray(self.cache["pos"]), cur.shape)
+        plen = pull(entry["page_len"], "ring_view")[0]       # (B, S)
+        cur = pull(entry["cur_slot"], "ring_view")[0]        # (B,)
+        pos = np.broadcast_to(pull(self.cache["pos"], "ring_view"),
+                              cur.shape)
         return plen, cur, pos
 
     @staticmethod
@@ -1038,28 +1063,31 @@ class ServeEngine:
         are metered as ``flush_bytes``.  Batch row 0 is the representative
         payload, matching the mass proxy in _kv_page_stream.
         """
-        h = self.daemon["kv"]
-        if h.mem.buffers is None:
-            return
-        entry = self._paged_entry()
-        if entry is None:
-            return
-        mass, ids = self._kv_page_stream()
-        if not ids.size:
-            return
-        ids = np.asarray(ids)
-        fill = np.asarray(mass, np.int64)            # per-slot page_len
-        changed = np.array([
-            self._kv_flushed.get((0, slot)) != (int(ids[slot]), int(fill[slot]))
-            for slot in range(ids.shape[0])])
-        ids = np.where(changed, ids, -1)             # -1 lanes are dropped
-        if not (ids >= 0).any():
-            return
-        # batch row 0 is the representative payload; the [K|V] concat +
-        # slot-major transpose + dual-tier scatter fuse in ONE donated op
-        h.write_pages(ids, entry["k_pages"][:, :1], entry["v_pages"][:, :1])
-        for slot in np.flatnonzero(ids >= 0):
-            self._kv_flushed[(0, slot)] = (int(ids[slot]), int(fill[slot]))
+        with span("tier/flush"):
+            h = self.daemon["kv"]
+            if h.mem.buffers is None:
+                return
+            entry = self._paged_entry()
+            if entry is None:
+                return
+            mass, ids = self._kv_page_stream()
+            if not ids.size:
+                return
+            ids = pull(ids, "ring_view")
+            fill = np.asarray(pull(mass, "ring_view"), np.int64)  # page_len
+            changed = np.array([
+                self._kv_flushed.get((0, slot))
+                != (int(ids[slot]), int(fill[slot]))
+                for slot in range(ids.shape[0])])
+            ids = np.where(changed, ids, -1)             # -1 lanes are dropped
+            if not (ids >= 0).any():
+                return
+            # batch row 0 is the representative payload; the [K|V] concat +
+            # slot-major transpose + dual-tier scatter fuse in ONE donated op
+            h.write_pages(ids, entry["k_pages"][:, :1],
+                          entry["v_pages"][:, :1])
+            for slot in np.flatnonzero(ids >= 0):
+                self._kv_flushed[(0, slot)] = (int(ids[slot]), int(fill[slot]))
 
     def _flush_kv_lanes(self, lanes=None, force: bool = False) -> None:
         """Lane-mode KV flush: every active lane's resident ring pages go
@@ -1076,52 +1104,53 @@ class ServeEngine:
         wrote into it) forks: the page-table entry reverts to the lane's
         private segment page and the payload flushes there, so other
         referencing lanes keep the pool copy untouched."""
-        h = self.daemon["kv"]
-        if h.mem.buffers is None:
-            return
-        entry = self._paged_entry()
-        if entry is None:
-            return
-        view = self._ring_view()
-        if view is None:
-            return
-        plen, cur, pos = view
-        local = self._ring_page_ids(plen, cur, pos, self.scfg.page_t)
-        if lanes is None:
-            act = self._lane_active
-        else:
-            act = np.zeros(self.scfg.lanes, bool)
-            act[np.asarray(lanes, int)] = True
-        gids = self._map_gids(local, act)            # (L, S)
-        fill = np.where(gids >= 0, plen, 0).astype(np.int64)
-        base = self.reuse.base_gid if self.reuse is not None else None
-        ids = gids.copy()
-        for lane, slot in np.argwhere(ids >= 0):
-            key = (int(lane), int(slot))
-            state = (int(gids[lane, slot]), int(fill[lane, slot]))
-            if base is not None and gids[lane, slot] >= base:
-                if self._kv_flushed.get(key) == state:
-                    ids[lane, slot] = -1             # clean shared page: CoW
-                    continue
-                lp = int(local[lane, slot])          # dirty: private fork
-                self._lane_pages[lane, lp] = -1
-                priv = (int(self._lane_segments[lane]) * self.pages_per_seq
-                        + lp)
-                ids[lane, slot] = gids[lane, slot] = priv
-                state = (priv, int(fill[lane, slot]))
-            if not force and self._kv_flushed.get(key) == state:
-                ids[lane, slot] = -1
-        if not (ids >= 0).any():
-            return
-        # bulk page-write verb: the (G, L, S, T, hkv, d) ring views go down
-        # as ONE donated fused [K|V]-concat + transpose + dual-tier scatter
-        h.write_pages(ids.reshape(-1), entry["k_pages"], entry["v_pages"])
-        for lane, slot in np.argwhere(ids >= 0):
-            self._kv_flushed[(int(lane), int(slot))] = (
-                int(gids[lane, slot]), int(fill[lane, slot]))
-            if fill[lane, slot] >= self.scfg.page_t:
-                # witness: this local's slow row holds the complete page
-                self._lane_full[lane, local[lane, slot]] = True
+        with span("tier/flush"):
+            h = self.daemon["kv"]
+            if h.mem.buffers is None:
+                return
+            entry = self._paged_entry()
+            if entry is None:
+                return
+            view = self._ring_view()
+            if view is None:
+                return
+            plen, cur, pos = view
+            local = self._ring_page_ids(plen, cur, pos, self.scfg.page_t)
+            if lanes is None:
+                act = self._lane_active
+            else:
+                act = np.zeros(self.scfg.lanes, bool)
+                act[np.asarray(lanes, int)] = True
+            gids = self._map_gids(local, act)            # (L, S)
+            fill = np.where(gids >= 0, plen, 0).astype(np.int64)
+            base = self.reuse.base_gid if self.reuse is not None else None
+            ids = gids.copy()
+            for lane, slot in np.argwhere(ids >= 0):
+                key = (int(lane), int(slot))
+                state = (int(gids[lane, slot]), int(fill[lane, slot]))
+                if base is not None and gids[lane, slot] >= base:
+                    if self._kv_flushed.get(key) == state:
+                        ids[lane, slot] = -1         # clean shared page: CoW
+                        continue
+                    lp = int(local[lane, slot])          # dirty: private fork
+                    self._lane_pages[lane, lp] = -1
+                    priv = (int(self._lane_segments[lane]) * self.pages_per_seq
+                            + lp)
+                    ids[lane, slot] = gids[lane, slot] = priv
+                    state = (priv, int(fill[lane, slot]))
+                if not force and self._kv_flushed.get(key) == state:
+                    ids[lane, slot] = -1
+            if not (ids >= 0).any():
+                return
+            # bulk page-write verb: the (G, L, S, T, hkv, d) ring views go down
+            # as ONE donated fused [K|V]-concat + transpose + dual-tier scatter
+            h.write_pages(ids.reshape(-1), entry["k_pages"], entry["v_pages"])
+            for lane, slot in np.argwhere(ids >= 0):
+                self._kv_flushed[(int(lane), int(slot))] = (
+                    int(gids[lane, slot]), int(fill[lane, slot]))
+                if fill[lane, slot] >= self.scfg.page_t:
+                    # witness: this local's slow row holds the complete page
+                    self._lane_full[lane, local[lane, slot]] = True
 
     def read_rows(self, name: str, page_ids) -> jax.Array:
         """Serve payload rows for a resource: fast-tier copy when the page

@@ -65,6 +65,7 @@ traffic_bench.py` emits both).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 
@@ -74,6 +75,7 @@ import numpy as np
 
 from repro.models import decode as dec
 from repro.serve.engine import ServeEngine
+from repro.spans import pull, span
 from repro.tiering.daemon import split_quota
 from repro.tiering.stats import TierStats
 
@@ -202,8 +204,7 @@ class Scheduler:
         # everything on "decode"; disagg charges each pool's engine/host
         # work to its own worker
         self.clock = {"prefill": 0.0, "handoff": 0.0, "decode": 0.0}
-        self._seg_role: str | None = None
-        self._seg_t0 = 0.0
+        self._turn_open: tuple[str, span] | None = None   # (role, its span)
         # prefill_busy[s]: was a prefill in flight during step s?  (the
         # disagg A/B's gap classifier — maintained in both modes)
         self.prefill_busy: list[bool] = []
@@ -256,29 +257,33 @@ class Scheduler:
                       arrival_time=time.perf_counter())
         if self.scfg.temperature > 0.0:
             # identity-derived key: (seed, rid) — lane/preemption-invariant
-            req.key = np.asarray(
-                jax.random.fold_in(self._sample_master, req.rid))
+            req.key = pull(jax.random.fold_in(self._sample_master, req.rid),
+                           "sample_key")
         self._next_rid += 1
         self.queue.append(req)
         self.queued_peak = max(self.queued_peak, len(self.queue))
         return req
 
     # -- worker clocks --------------------------------------------------------
-    def _enter(self, role: str) -> None:
-        """Start charging wall time to ``role``'s virtual clock."""
-        self._close_seg()
-        self._seg_role, self._seg_t0 = role, time.perf_counter()
-
-    def _close_seg(self) -> None:
-        if self._seg_role is not None:
-            self.clock[self._seg_role] += time.perf_counter() - self._seg_t0
-            self._seg_role = None
+    @contextlib.contextmanager
+    def _turn(self, role: str):
+        """Charge the block's wall time, its ``sched/turn`` span, to
+        ``role``'s virtual clock.  A hand-off install inside it is charged
+        to the hand-off clock instead (``_install``)."""
+        sp = span("sched/turn", role=role)
+        self._turn_open = (role, sp)
+        try:
+            with sp:
+                yield
+        finally:
+            self._turn_open = None
+            self.clock[role] += sp.elapsed
 
     def _now(self, role: str) -> float:
-        """``role``'s virtual clock reading, mid-segment included."""
+        """``role``'s virtual clock reading, the open turn included."""
         t = self.clock[role]
-        if self._seg_role == role:
-            t += time.perf_counter() - self._seg_t0
+        if self._turn_open is not None and self._turn_open[0] == role:
+            t += self._turn_open[1].elapsed
         return t
 
     # -- admission / preemption ----------------------------------------------
@@ -328,37 +333,42 @@ class Scheduler:
         return split_quota(n_pool, demands, caps, weights)
 
     def _admit_pool(self, role: str) -> None:
-        _, lanes = self._pool(role)
-        if self._candidates(role):
-            self._maybe_preempt(role)
-        free = [ln for ln, r in enumerate(lanes) if r is None]
-        while free:
-            cands = self._candidates(role)
-            if not cands:
-                break
-            shares = self._lane_shares(role, cands)
-            running = self._running_by_tenant(lanes)
-            heads: dict[str, Request] = {}
-            for r in cands:                  # service order: first is head
-                heads.setdefault(r.tenant, r)
-            # the waiting tenant with the largest share deficit wins the
-            # lane; deficit <= 0 everywhere falls back to FIFO
-            pick = max(heads.values(),
-                       key=lambda r: (shares.get(r.tenant, 0)
-                                      - running[r.tenant],
-                                      -r.queued_since, -r.rid))
-            if shares.get(pick.tenant, 0) - running[pick.tenant] <= 0:
-                pick = cands[0]
-            if not self._install(pick, free[0], role):
-                # no free KV segment for a fresh request — a preempted one
-                # (which kept its segment) can still take the lane
-                pre = next((r for r in cands
-                            if r.state == "preempted"), None)
-                if pre is None or not self._install(pre, free[0], role):
+        with span("sched/admit", pool=role):
+            _, lanes = self._pool(role)
+            if self._candidates(role):
+                self._maybe_preempt(role)
+            free = [ln for ln, r in enumerate(lanes) if r is None]
+            while free:
+                cands = self._candidates(role)
+                if not cands:
                     break
-            free.pop(0)
+                shares = self._lane_shares(role, cands)
+                running = self._running_by_tenant(lanes)
+                heads: dict[str, Request] = {}
+                for r in cands:              # service order: first is head
+                    heads.setdefault(r.tenant, r)
+                # the waiting tenant with the largest share deficit wins
+                # the lane; deficit <= 0 everywhere falls back to FIFO
+                pick = max(heads.values(),
+                           key=lambda r: (shares.get(r.tenant, 0)
+                                          - running[r.tenant],
+                                          -r.queued_since, -r.rid))
+                if shares.get(pick.tenant, 0) - running[pick.tenant] <= 0:
+                    pick = cands[0]
+                if not self._install(pick, free[0], role):
+                    # no free KV segment for a fresh request — a preempted
+                    # one (which kept its segment) can still take the lane
+                    pre = next((r for r in cands
+                                if r.state == "preempted"), None)
+                    if pre is None or not self._install(pre, free[0], role):
+                        break
+                free.pop(0)
 
     def _install(self, req: Request, lane: int, role: str = "decode") -> bool:
+        with span("sched/install", rid=req.rid):
+            return self._install_request(req, lane, role)
+
+    def _install_request(self, req: Request, lane: int, role: str) -> bool:
         eng, lanes = self._pool(role)
         if req.state == "handoff":
             # decode-side hand-off completion (DESIGN.md §13): pull the
@@ -372,9 +382,11 @@ class Scheduler:
             # keeps only what decode actually executes — the placement-
             # table slow-tier pulls during advance — so hand-off traffic
             # shows up in clock.handoff_s and bytes_in, not as fake TPOT
-            self._enter("handoff")
-            self.handoff_bytes_in += eng.install_handoff(lane, residual)
-            self._enter("decode")
+            with span("sched/handoff", rid=req.rid) as sp:
+                self.handoff_bytes_in += eng.install_handoff(lane, residual)
+            self.clock["handoff"] += sp.elapsed
+            if self._turn_open is not None:
+                self.clock[self._turn_open[0]] -= sp.elapsed
             req.residual = None
             self.handoff.remove(req)
             req.state, req.lane = "running", lane
@@ -473,21 +485,23 @@ class Scheduler:
         self.handoff_peak = max(self.handoff_peak, len(self.handoff))
 
     def _finish(self, req: Request) -> None:
-        if self.eng.reuse is not None:
-            # publish BEFORE the segment is recycled (the pool copy sources
-            # from it), then drop this request's claims on shared pages
-            stream = (np.concatenate(
-                [req.prompt, np.asarray(req.out[:-1], np.int32)])
-                if len(req.out) > 1 else req.prompt)
-            self.eng.publish_lane(req.lane, stream)
-            if req.shared_gids:
-                self.eng.reuse.release(req.shared_gids)
-                req.shared_gids = []
-        self.lanes[req.lane] = None
-        self.free_segments.append(req.segment)
-        req.state, req.lane = "finished", -1
-        req.finished_step = self.step_count
-        self.finished.append(req)
+        with span("sched/finish", rid=req.rid):
+            if self.eng.reuse is not None:
+                # publish BEFORE the segment is recycled (the pool copy
+                # sources from it), then drop this request's claims on
+                # shared pages
+                stream = (np.concatenate(
+                    [req.prompt, np.asarray(req.out[:-1], np.int32)])
+                    if len(req.out) > 1 else req.prompt)
+                self.eng.publish_lane(req.lane, stream)
+                if req.shared_gids:
+                    self.eng.reuse.release(req.shared_gids)
+                    req.shared_gids = []
+            self.lanes[req.lane] = None
+            self.free_segments.append(req.segment)
+            req.state, req.lane = "finished", -1
+            req.finished_step = self.step_count
+            self.finished.append(req)
 
     # -- token emission -------------------------------------------------------
     def _emit(self, req: Request, logits_row: np.ndarray) -> None:
@@ -508,9 +522,10 @@ class Scheduler:
         folded = dec.fold_lane_keys(
             jnp.asarray(req.key[None, :]),
             jnp.asarray([len(req.out)], jnp.uint32))
-        return int(np.asarray(dec.sample_tokens(
+        return int(pull(dec.sample_tokens(
             jnp.asarray(row[None]), folded,
-            temperature=self.scfg.temperature, top_p=self.scfg.top_p))[0])
+            temperature=self.scfg.temperature, top_p=self.scfg.top_p),
+            "sample")[0])
 
     # -- the serving loop -----------------------------------------------------
     def step(self) -> None:
@@ -532,24 +547,24 @@ class Scheduler:
         install per busy prefill lane) on the prefill clock, then the
         decode worker's turn (one batched decode step over the decode
         lanes) on the decode clock."""
-        self._enter("decode")
-        try:
+        with span("sched/step", step=self.step_count):
             if self.disagg:
                 self._step_disagg()
             else:
-                self._step_unified()
-        finally:
-            self._close_seg()
+                with self._turn("decode"):
+                    self._step_unified()
 
     def _step_disagg(self) -> None:
-        self._admit_pool("decode")           # hand-offs may emit first tokens
-        self._enter("prefill")
-        self._admit_pool("prefill")
-        self.prefill_busy.append(any(r is not None for r in self.pre_lanes))
-        self._prefill_turn()
-        self._enter("decode")
-        self._decode_turn()
-        self.step_count += 1
+        with self._turn("decode"):
+            self._admit_pool("decode")       # hand-offs may emit first tokens
+        with self._turn("prefill"):
+            self._admit_pool("prefill")
+            self.prefill_busy.append(
+                any(r is not None for r in self.pre_lanes))
+            self._prefill_turn()
+        with self._turn("decode"):
+            self._decode_turn()
+            self.step_count += 1
 
     def _prefill_turn(self) -> None:
         """The prefill worker's step: each busy prefill lane consumes one
@@ -580,8 +595,9 @@ class Scheduler:
             gap = min((jj * page_t for jj in req.matched
                        if jj * page_t >= req.pos), default=end)
             piece = req.prompt[req.pos:min(end, gap)]
-            logits = self.peng.prefill_lane(lane, piece, req.segment,
-                                            chunk=chunk)
+            with span("sched/prefill", rid=req.rid):
+                logits = self.peng.prefill_lane(lane, piece, req.segment,
+                                                chunk=chunk)
             req.pos += int(piece.size)
             if not req.prefilling:
                 self._to_handoff(lane, req, np.asarray(logits))
@@ -609,19 +625,20 @@ class Scheduler:
         self._meter_pool(self.eng, self.lanes)
         now = time.perf_counter()
         clock_now = self._now("decode")
-        sampled = self._sample(logits, active.astype(np.int32))
-        for lane, req in enumerate(list(self.lanes)):
-            if req is None:
-                continue
-            req.pos += 1
-            tok = (int(sampled[lane]) if sampled is not None
-                   else int(np.argmax(logits[lane])))
-            req.out.append(tok)
-            req.token_times.append(now)
-            req.token_clock.append(clock_now)
-            req.token_steps.append(self.step_count)
-            if len(req.out) >= req.max_new:
-                self._finish(req)
+        with span("sched/sample"):
+            sampled = self._sample(logits, active.astype(np.int32))
+            for lane, req in enumerate(list(self.lanes)):
+                if req is None:
+                    continue
+                req.pos += 1
+                tok = (int(sampled[lane]) if sampled is not None
+                       else int(np.argmax(logits[lane])))
+                req.out.append(tok)
+                req.token_times.append(now)
+                req.token_clock.append(clock_now)
+                req.token_steps.append(self.step_count)
+                if len(req.out) >= req.max_new:
+                    self._finish(req)
 
     def _step_unified(self) -> None:
         self._admit_pool("decode")
@@ -667,8 +684,9 @@ class Scheduler:
                 gap = min((jj * page_t for jj in req.matched
                            if jj * page_t >= req.pos), default=end)
                 piece = req.prompt[req.pos:min(end, gap)]
-                chunk_logits[lane] = self.eng.prefill_lane(
-                    lane, piece, req.segment, chunk=chunk)
+                with span("sched/prefill", rid=req.rid):
+                    chunk_logits[lane] = self.eng.prefill_lane(
+                        lane, piece, req.segment, chunk=chunk)
                 consumed[lane] = piece.size
                 continue
             active[lane] = True
@@ -698,20 +716,21 @@ class Scheduler:
         self._meter_tenants()
         now = time.perf_counter()
         clock_now = self._now("decode")
-        sampled = self._sample(logits, consumed)
-        for lane, req in enumerate(list(self.lanes)):
-            if req is None or consumed[lane] == 0:
-                continue
-            req.pos += int(consumed[lane])
-            if not req.prefilling:           # last prompt token or decoding
-                tok = (int(sampled[lane]) if sampled is not None
-                       else int(np.argmax(logits[lane])))
-                req.out.append(tok)
-                req.token_times.append(now)
-                req.token_clock.append(clock_now)
-                req.token_steps.append(self.step_count)
-                if len(req.out) >= req.max_new:
-                    self._finish(req)
+        with span("sched/sample"):
+            sampled = self._sample(logits, consumed)
+            for lane, req in enumerate(list(self.lanes)):
+                if req is None or consumed[lane] == 0:
+                    continue
+                req.pos += int(consumed[lane])
+                if not req.prefilling:       # last prompt token or decoding
+                    tok = (int(sampled[lane]) if sampled is not None
+                           else int(np.argmax(logits[lane])))
+                    req.out.append(tok)
+                    req.token_times.append(now)
+                    req.token_clock.append(clock_now)
+                    req.token_steps.append(self.step_count)
+                    if len(req.out) >= req.max_new:
+                        self._finish(req)
         self.step_count += 1
 
     def _sample(self, logits: np.ndarray,
@@ -738,9 +757,10 @@ class Scheduler:
         if not emitting:
             return None
         folded = dec.fold_lane_keys(jnp.asarray(keys), jnp.asarray(idx))
-        return np.asarray(dec.sample_tokens(
+        return pull(dec.sample_tokens(
             jnp.asarray(logits), folded,
-            temperature=self.scfg.temperature, top_p=self.scfg.top_p))
+            temperature=self.scfg.temperature, top_p=self.scfg.top_p),
+            "sample")
 
     @property
     def active(self) -> bool:
@@ -768,22 +788,23 @@ class Scheduler:
         active mask no longer carries — is still charged."""
         if eng is None or "kv" not in eng.daemon:
             return
-        occupied = np.array([r is not None for r in lanes], bool)
-        sv = eng._kv_lane_stream(active=occupied)
-        if sv is None:
-            return
-        _, gids = sv
-        h = eng.daemon["kv"]
-        _, hit = h.lookup(jnp.asarray(gids.reshape(-1), jnp.int32))
-        hit = np.asarray(hit).reshape(gids.shape)
-        valid = gids >= 0
-        for lane, req in enumerate(lanes):
-            if req is None:
-                continue
-            st = self.tenant_stats[req.tenant]
-            f = int(np.sum(hit[lane] & valid[lane]))
-            st.fast_reads += f
-            st.slow_reads += int(np.sum(valid[lane])) - f
+        with span("sched/meter"):
+            occupied = np.array([r is not None for r in lanes], bool)
+            sv = eng._kv_lane_stream(active=occupied)
+            if sv is None:
+                return
+            _, gids = sv
+            h = eng.daemon["kv"]
+            _, hit = h.lookup(jnp.asarray(gids.reshape(-1), jnp.int32))
+            hit = pull(hit, "meter_hit").reshape(gids.shape)
+            valid = gids >= 0
+            for lane, req in enumerate(lanes):
+                if req is None:
+                    continue
+                st = self.tenant_stats[req.tenant]
+                f = int(np.sum(hit[lane] & valid[lane]))
+                st.fast_reads += f
+                st.slow_reads += int(np.sum(valid[lane])) - f
 
     @staticmethod
     def _pct_row(gaps) -> dict:

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.spans import pull, span
 from repro.tiering.memory import (DaemonParams, MigrationEvent, TieredMemory,
                                   TieredMemoryState, lookup)
 from repro.tiering.resource import TieredResource
@@ -143,9 +144,10 @@ class ResourceHandle:
         # the ONE placement lookup — against the COMMITTED view, so reads
         # issued mid-epoch resolve exactly like the payload gather below
         slots = self.mem.lookup_slots(self.state, ids)
-        hits = int(np.sum(np.asarray(slots) >= 0))
+        hits = int(np.sum(pull(slots, "lookup_slots") >= 0))
+        valid = int(np.sum(pull(ids, "lookup_slots") >= 0))
         self.stats.fast_reads += hits
-        self.stats.slow_reads += int(np.sum(np.asarray(ids) >= 0)) - hits
+        self.stats.slow_reads += valid - hits
         return self.mem.read_rows(self.state, ids, slots=slots)
 
     def write_rows(self, page_ids, rows) -> None:
@@ -172,8 +174,10 @@ class ResourceHandle:
         # merge the not-yet-drained device-side period counters so the read
         # counts are consistent with hit_rate() (which always merged them) —
         # a row must never report 0 reads next to a nonzero hit rate
-        row["fast_reads"] += int(self.state.tier.fast_reads)
-        row["slow_reads"] += int(self.state.tier.slow_reads)
+        row["fast_reads"] += int(pull(self.state.tier.fast_reads,
+                                      "tier_stats"))
+        row["slow_reads"] += int(pull(self.state.tier.slow_reads,
+                                      "tier_stats"))
         row["hit_rate"] = self.hit_rate()
         # fold the in-flight epoch the same way: a snapshot taken mid-epoch
         # must still satisfy last_epoch <= max_epoch <= quota row-level
@@ -237,6 +241,10 @@ class NeoMemDaemon:
 
     def tick(self) -> dict[str, MigrationEvent]:
         """One daemon tick: run whatever cadences are due, for ALL resources."""
+        with span("tier/tick"):
+            return self._run_cadences()
+
+    def _run_cadences(self) -> dict[str, MigrationEvent]:
         self._tick += 1
         t, dp = self._tick, self.dp
         events: dict[str, MigrationEvent] = {}
@@ -245,16 +253,18 @@ class NeoMemDaemon:
             # COMMIT phase first (async plane, DESIGN.md §15): witness each
             # in-flight epoch's readiness token and pointer-swap — never
             # blocks; an epoch whose copy has not landed stays in flight
-            for h in self.resources.values():
-                if h.mem.async_on:
-                    h.mem.commit_migration(h.stats)
+            with span("tier/commit"):
+                for h in self.resources.values():
+                    if h.mem.async_on:
+                        h.mem.commit_migration(h.stats)
             # PLAN phase (unchanged policy): drain hot pages, split the
             # shared budget.  A busy resource (epoch still uncommitted) is
             # capped at 0 — no N+2 issue before N+1 commits, and its share
             # flows to the others via the weighted max-min redistribution.
             demands: dict[str, int] = {}
-            for name, h in self.resources.items():
-                h.state, demands[name] = h.mem.collect(h.state, h.stats)
+            with span("tier/collect"):
+                for name, h in self.resources.items():
+                    h.state, demands[name] = h.mem.collect(h.state, h.stats)
             caps = {n: (0 if h.mem.busy else h.mem.quota)
                     for n, h in self.resources.items()}
             weights = {n: h.weight for n, h in self.resources.items()}
@@ -265,22 +275,26 @@ class NeoMemDaemon:
             for name, h in self.resources.items():
                 if h.mem.busy:
                     continue
-                h.state, event = h.mem.migrate(h.state, h.stats,
-                                               quota=shares.get(name, 0))
-                if event is not None:
-                    # data plane first (bytes metered), then the
-                    # resource's own hook
-                    h.mem.dispatch_migration(h.state, event, h.stats)
-                    h.resource.apply_migration(event.promoted, event.victims)
-                    events[name] = event
+                with span("tier/migrate", resource=name):
+                    h.state, event = h.mem.migrate(h.state, h.stats,
+                                                   quota=shares.get(name, 0))
+                    if event is not None:
+                        # data plane first (bytes metered), then the
+                        # resource's own hook
+                        h.mem.dispatch_migration(h.state, event, h.stats)
+                        h.resource.apply_migration(event.promoted,
+                                                   event.victims)
+                        events[name] = event
 
         if t % dp.threshold_update_period == 0:
-            for h in self.resources.values():
-                h.state = h.mem.update_threshold(h.state, h.stats)
+            with span("tier/threshold"):
+                for h in self.resources.values():
+                    h.state = h.mem.update_threshold(h.state, h.stats)
 
         if t % dp.clear_interval == 0:
-            for h in self.resources.values():
-                h.state = h.mem.clear(h.state)
+            with span("tier/clear"):
+                for h in self.resources.values():
+                    h.state = h.mem.clear(h.state)
         return events
 
     # -- checkpointing (DESIGN.md §6) ----------------------------------------

@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import NamedTuple
 
 import jax
@@ -37,6 +36,7 @@ from repro.core.neoprof import (NeoProfCommands, NeoProfParams, NeoProfState,
 from repro.core.policy import PolicyParams, PolicyState
 from repro.core.policy import update_threshold as _algorithm1
 from repro.core.tiering import TierParams, TierState
+from repro.spans import pull, span
 from repro.tiering import codec as codec_lib
 from repro.tiering import migrate as migrate_lib
 from repro.tiering.stats import TierStats, drain_tier_stats
@@ -174,6 +174,11 @@ class TieredMemory:
         self._inflight: InFlightEpoch | None = None
         self._committed_slot: jax.Array | None = None
 
+    @property
+    def _name(self) -> str:
+        """The resource's name, for span attributes ("" without a spec)."""
+        return self.spec.name if self.spec is not None else ""
+
     @classmethod
     def from_spec(cls, spec, daemon_params=None, policy_params=None,
                   fixed_theta=None) -> "TieredMemory":
@@ -237,15 +242,16 @@ class TieredMemory:
             return 0
         evicted = (event.evicted if event.evicted is not None
                    else jnp.full_like(jnp.asarray(event.victims), -1))
-        t0 = time.perf_counter()
-        self.buffers, n_up, n_down = migrate_lib.migrate(
-            self.buffers, event.promoted, event.victims, evicted,
-            codec=self.codec)
-        # the synchronous arm stops the world: the donated fused copy must
-        # land before the next decode step can read the swapped buffers —
-        # that wait is exactly the stall the async plane (§15) removes
-        jax.block_until_ready(self.buffers.fast)
-        stats.stall_s += time.perf_counter() - t0
+        with span("tier/stall", resource=self._name) as sp:
+            self.buffers, n_up, n_down = migrate_lib.migrate(
+                self.buffers, event.promoted, event.victims, evicted,
+                codec=self.codec)
+            # the synchronous arm stops the world: the donated fused copy
+            # must land before the next decode step can read the swapped
+            # buffers — that wait is exactly the stall the async plane
+            # (§15) removes
+            jax.block_until_ready(self.buffers.fast)
+        stats.stall_s += sp.elapsed
         moved = (n_up + n_down) * self.row_bytes
         stats.migration_bytes += moved
         stats.last_epoch_bytes = moved
@@ -304,10 +310,11 @@ class TieredMemory:
                 "N+1 before issuing N+2")
         # host-side byte accounting off the tiny promote outputs (these are
         # products of tiering.promote's executable, NOT the bulk copy — the
-        # np.asarray below never waits on payload movement)
-        ok = (np.asarray(event.promoted) >= 0) & (np.asarray(event.victims) >= 0)
+        # pulls below never wait on payload movement)
+        ok = ((pull(event.promoted, "epoch_plan") >= 0)
+              & (pull(event.victims, "epoch_plan") >= 0))
         if event.evicted is not None:
-            n_down = int(np.sum(ok & (np.asarray(event.evicted) >= 0)))
+            n_down = int(np.sum(ok & (pull(event.evicted, "epoch_plan") >= 0)))
         else:
             n_down = 0
         new_fast, token = migrate_lib.issue_migrate(
@@ -338,9 +345,9 @@ class TieredMemory:
         if not migrate_lib.token_ready(fl.token):
             if not block:
                 return 0
-            t0 = time.perf_counter()
-            jax.block_until_ready(fl.fast)
-            stats.stall_s += time.perf_counter() - t0
+            with span("tier/stall", resource=self._name) as sp:
+                jax.block_until_ready(fl.fast)
+            stats.stall_s += sp.elapsed
         self.buffers = self.buffers._replace(fast=fl.fast)
         self._committed_slot = fl.page_slot
         self._inflight = None
@@ -450,8 +457,8 @@ class TieredMemory:
         page_ids = jnp.asarray(page_ids, jnp.int32)
         if slots is None:
             slots = self.lookup_slots(state, page_ids)
-        slots_np = np.asarray(slots)
-        ids_np = np.maximum(np.asarray(page_ids), 0)
+        slots_np = pull(slots, "lookup_slots")
+        ids_np = np.maximum(pull(page_ids, "lookup_slots"), 0)
         hit = slots_np >= 0
         if hit.all():
             return self.buffers.fast[slots]
@@ -519,9 +526,10 @@ class TieredMemory:
             self._inflight.fast = migrate_lib.refresh_copy(
                 self._inflight.fast, self.buffers.slow, self.buffers.scale,
                 src_ids, self._inflight_slots(dst_ids))
-        valid = (np.asarray(src_ids) >= 0) & (np.asarray(dst_ids) >= 0)
+        src_np, dst_np = pull(src_ids, "copy_ids"), pull(dst_ids, "copy_ids")
+        valid = (src_np >= 0) & (dst_np >= 0)
         if self.written is not None:
-            self.written[np.asarray(dst_ids)[valid]] = True
+            self.written[dst_np[valid]] = True
         return int(np.sum(valid))
 
     def _mark_written(self, page_ids) -> int:
@@ -579,7 +587,8 @@ class TieredMemory:
         """Reconstruct the Algorithm-1 view from the pytree (+ telemetry)."""
         last = lambda tr, d: tr[-1] if stats is not None and tr else d
         return PolicyState(
-            p=float(state.p), theta=int(state.prof.theta),
+            p=float(pull(state.p, "policy")),
+            theta=int(pull(state.prof.theta, "policy")),
             last_B=last(stats.bw_trace if stats else [], 0.0),
             last_P=last(stats.pp_trace if stats else [], 0.0),
             last_E=int(last(stats.err_trace if stats else [], 0)),
@@ -634,7 +643,7 @@ class TieredMemory:
         # write-back targets for the data plane (apply_migration)
         evicted = jnp.where(victims >= 0,
                             old_slot_page[jnp.maximum(victims, 0)], -1)
-        n = int(np.sum(np.asarray(promoted) >= 0))
+        n = int(np.sum(pull(promoted, "epoch_plan") >= 0))
         stats.migrated_this_period += n
         stats.pending = len(self._pending)
         return state._replace(tier=tier), MigrationEvent(promoted, victims, n,
@@ -662,7 +671,8 @@ class TieredMemory:
             # when the migrator runs at capacity.
             demand = stats.migrated_this_period + len(self._pending)
             pol = _algorithm1(
-                PolicyState(p=float(state.p), theta=int(state.prof.theta)),
+                PolicyState(p=float(pull(state.p, "policy")),
+                            theta=int(pull(state.prof.theta, "policy"))),
                 self.pol_params, hist, bandwidth_util=bw,
                 ping_pong_ratio=pp_ratio, migrated_pages=demand,
                 error_bound=err)
@@ -670,11 +680,11 @@ class TieredMemory:
                 prof=self.cmd.set_threshold(state.prof, pol.theta),
                 p=jnp.float32(pol.p))
         stats.migrated_this_period = 0
-        stats.theta_trace.append(int(state.prof.theta))
+        stats.theta_trace.append(int(pull(state.prof.theta, "policy")))
         stats.bw_trace.append(float(bw))
         stats.pp_trace.append(pp_ratio)
         stats.err_trace.append(int(err))
-        stats.p_trace.append(float(state.p))
+        stats.p_trace.append(float(pull(state.p, "policy")))
         return state
 
     def clear(self, state: TieredMemoryState) -> TieredMemoryState:
@@ -685,7 +695,7 @@ class TieredMemory:
         """Single-resource cadence driver (the multiplexed daemon drives the
         verbs itself so it can split the quota budget across resources)."""
         state = state._replace(tick=state.tick + 1)
-        t, dp, event = int(state.tick), self.dp, None
+        t, dp, event = int(pull(state.tick, "policy")), self.dp, None
         if t % dp.migration_interval == 0:
             if self.async_on:
                 self.commit_migration(stats)   # commit FIRST, never blocks

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.dist import host_offload as ho
+from repro.spans import pull
 from repro.tiering import codec as codec_lib
 
 
@@ -218,7 +219,8 @@ def migrate(buffers: TierBuffers, promoted: jax.Array, victims: jax.Array,
         buffers.fast, buffers.slow, buffers.scale,
         jnp.asarray(promoted, jnp.int32), jnp.asarray(victims, jnp.int32),
         jnp.asarray(evicted, jnp.int32))
-    return _rehosted(buffers, fast, slow, scale), int(n_up), int(n_down)
+    return (_rehosted(buffers, fast, slow, scale),
+            int(pull(n_up, "epoch_plan")), int(pull(n_down, "epoch_plan")))
 
 
 def read_rows(fast: jax.Array, slow: jax.Array, slots: jax.Array,

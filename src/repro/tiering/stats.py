@@ -13,6 +13,7 @@ import dataclasses
 
 from repro.core import tiering
 from repro.core.tiering import TierState
+from repro.spans import pull
 
 
 @dataclasses.dataclass
@@ -105,20 +106,21 @@ def drain_tier_stats(tier: TierState, stats: TierStats) -> TierState:
     aged, per 2Q CLOCK second-chance — see tiering.drain_period_stats).
     """
     tier, period = tiering.drain_period_stats(tier)
-    stats.fast_reads += int(period["fast_reads"])
-    stats.slow_reads += int(period["slow_reads"])
-    stats.promoted += int(period["promoted"])
-    stats.demoted += int(period["demoted"])
-    stats.ping_pong += int(period["ping_pong"])
+    period = {k: int(pull(v, "drain_stats")) for k, v in period.items()}
+    stats.fast_reads += period["fast_reads"]
+    stats.slow_reads += period["slow_reads"]
+    stats.promoted += period["promoted"]
+    stats.demoted += period["demoted"]
+    stats.ping_pong += period["ping_pong"]
     # stash the raw period view for the caller's policy step
-    stats.last_period = {k: int(v) for k, v in period.items()}
+    stats.last_period = period
     return tier
 
 
 def hit_rate(tier: TierState, stats: TierStats) -> float:
     """Lifetime fast-tier hit rate = drained totals + not-yet-drained counts."""
-    f = stats.fast_reads + int(tier.fast_reads)
-    s = stats.slow_reads + int(tier.slow_reads)
+    f = stats.fast_reads + int(pull(tier.fast_reads, "tier_stats"))
+    s = stats.slow_reads + int(pull(tier.slow_reads, "tier_stats"))
     return f / max(f + s, 1)
 
 
