@@ -68,6 +68,20 @@ from repro.serve.clock import TickClock
 from repro.spans import pull, span
 
 
+@jax.jit
+def _live_mass(mass: jax.Array, live) -> jax.Array:
+    """(L, S) per-page mass with the pages outside ``live`` zeroed, flat —
+    the KV observation's mass, built where the kernel left it."""
+    return jnp.where(live, mass.astype(jnp.float32), 0.0).reshape(-1)
+
+
+@jax.jit
+def _mass_split(mass: jax.Array, gids, base) -> tuple[jax.Array, jax.Array]:
+    """(resident, shared-pool) sums of a flat observation mass."""
+    return (jnp.sum(jnp.where(gids >= 0, mass, 0.0)),
+            jnp.sum(jnp.where(gids >= base, mass, 0.0)))
+
+
 @dataclasses.dataclass
 class ServeConfig:
     max_seq: int = 4096
@@ -181,6 +195,10 @@ class ServeEngine:
         self._clock = TickClock(scfg.migration_interval)
         self._decode_s = 0.0            # decode wall time (overlap metering)
         self._last_kv_mass = None       # (B, n_slots) kernel mass, post-step
+        # host copy of every row's cache position (lane mode: per lane;
+        # single-request mode: the lockstep counter per batch row) — the
+        # ring view is derived from it, never read back (_ring_view)
+        self._pos = np.zeros(max(scfg.lanes, 1), np.int32)
         # (lane, slot) -> (page id, fill) change tracking for the KV flush
         # (single-request mode uses lane 0)
         self._kv_flushed: dict[tuple[int, int], tuple[int, int]] = {}
@@ -274,6 +292,10 @@ class ServeEngine:
             # resident (write-witnessed) once a flush lands on them; every
             # other resource binds a payload that is valid from step 0
             handle.bind_data(payload, initially_valid=(kind != "kv"))
+            # the KV observation is built on the step's device from its
+            # outputs, so the tier programs take committed state from the
+            # first call on (the daemon's ticks keep it so)
+            handle.state = self._commit(handle.state)
 
     # -- payload construction (the migration data plane, DESIGN.md §8) -------
     def _kv_row_shape(self) -> tuple[int, ...]:
@@ -394,6 +416,7 @@ class ServeEngine:
                              "advance_lanes (the request scheduler), not "
                              "prefill/generate")
         b, s = tokens.shape
+        self._pos = np.zeros(b, np.int32)
         self.aux = aux_embeds
         if self.cfg.encoder_layers and aux_embeds is not None:
             self.aux = tr.encode(self.cfg, self.params, aux_embeds)
@@ -418,6 +441,7 @@ class ServeEngine:
         logits, self.cache, streams = self._prefill_dense_jit(
             self.params, self.cache, jnp.asarray(tokens), self.aux,
             self._tier_reads())
+        self._pos += s
         self._observe_prefill(tokens, streams)
         self._maybe_tick(s)
         return pull(jnp.argmax(logits, -1), "logits")
@@ -429,6 +453,7 @@ class ServeEngine:
         n = tok.shape[1]
         logits, self.cache, streams = self._prefill_paged_jit(
             self.params, self.cache, tok, None, None, self._tier_reads())
+        self._pos += n
         self._observe_prefill(pull(tok, "tokens"), streams)
         if "kv" in self.daemon:
             with span("tier/observe", resource="kv"):
@@ -497,6 +522,7 @@ class ServeEngine:
                                                scfg.page_t, per_lane_pos=True)
         self.aux = None
         self._kv_flushed.clear()
+        self._pos = np.zeros(scfg.lanes, np.int32)
         self._lane_active = np.zeros(scfg.lanes, bool)
         self._lane_segments = np.full(scfg.lanes, -1, np.int32)
         self._lane_pages = np.full((scfg.lanes, self.pages_per_seq), -1,
@@ -529,11 +555,16 @@ class ServeEngine:
                 logits, self.cache, streams = out
             else:
                 (logits, self.cache), streams = out, {}
+            self._pos[self._lane_active] += 1
+            # the step's one host sync: its logits' copy starts now and is
+            # read last, so the observations and the tick are dispatched
+            # behind the step while the device runs it
+            last = logits[:, -1]
+            last.copy_to_host_async()
             self._set_kv_mass(streams)
             self._observe_lanes(tokens, streams)
             self._maybe_tick()
-            # host sync = the step's end
-            out_logits = pull(logits[:, -1], "logits")
+            out_logits = pull(last, "logits")
         self._decode_s += sp.elapsed
         return out_logits
 
@@ -586,6 +617,7 @@ class ServeEngine:
             logits, self.cache, streams = self._prefill_paged_jit(
                 self.params, self.cache, jnp.asarray(tok), jnp.asarray(valid),
                 jnp.asarray(active), self._tier_reads())
+            self._pos[lane] += n
             self._lane_active = active.copy()
             self._observe_lane_chunk(lane, tok, valid, streams, active)
             self._flush_kv_lanes(lanes=[lane])
@@ -622,10 +654,11 @@ class ServeEngine:
                     agg = jnp.sum(
                         per_step * jnp.asarray(valid.T)[:, :, None],
                         axis=0)                               # (L, S)
-                    mass = np.where(gids >= 0, np.asarray(
-                        pull(agg, "kv_mass"), np.float32), 0.0)
+                    mass = _live_mass(agg, gids >= 0)
+                else:
+                    mass = jnp.asarray(mass.reshape(-1))
                 self._count_shared_mass(mass, gids)
-                self.daemon.observe("kv", jnp.asarray(mass.reshape(-1)),
+                self.daemon.observe("kv", mass,
                                     jnp.asarray(gids.reshape(-1), jnp.int32))
 
     def _observe_lanes(self, tokens: np.ndarray, streams: dict) -> None:
@@ -648,13 +681,13 @@ class ServeEngine:
                     return
                 mass, gids = sv
                 if self._kernel_mass and self._last_kv_mass is not None:
-                    # per-lane kernel mass, masked to the live lanes'
-                    # segment-mapped pages (same mask the gids carry)
-                    km = np.asarray(pull(self._last_kv_mass, "kv_mass"),
-                                    np.float32)
-                    mass = np.where(gids >= 0, km, 0.0)
+                    # per-lane kernel mass, masked on the device to the live
+                    # lanes' segment-mapped pages (same mask the gids carry)
+                    mass = _live_mass(self._last_kv_mass, gids >= 0)
+                else:
+                    mass = jnp.asarray(mass.reshape(-1))
                 self._count_shared_mass(mass, gids)
-                self.daemon.observe("kv", jnp.asarray(mass.reshape(-1)),
+                self.daemon.observe("kv", mass,
                                     jnp.asarray(gids.reshape(-1), jnp.int32))
 
     def reset_lane(self, lane: int) -> None:
@@ -675,6 +708,7 @@ class ServeEngine:
                                self._lane_init.get("prologue", [])):
             clear(entry, tmpl, lane, 0)
         self.cache["pos"] = self.cache["pos"].at[lane].set(0)
+        self._pos[lane] = 0
         self._invalidate_lane_flush(lane)
         self._lane_pages[lane] = -1
         self._lane_full[lane] = False
@@ -690,7 +724,7 @@ class ServeEngine:
         the dense prologue ring) is snapshotted host-side into the returned
         residual.  :meth:`resume_lane` restores bit-exactly."""
         self._flush_kv_lanes(lanes=[lane], force=True)
-        residual = {"pos": int(pull(self.cache["pos"], "lane_state")[lane]),
+        residual = {"pos": int(self._pos[lane]),
                     "segment": int(self._lane_segments[lane]),
                     # page-table row + publish witnesses travel with the
                     # request: its claim on shared pool pages survives the
@@ -728,6 +762,7 @@ class ServeEngine:
             for k, v in snap.items():
                 entry[k] = entry[k].at[lane].set(jnp.asarray(v, entry[k].dtype))
         self.cache["pos"] = self.cache["pos"].at[lane].set(residual["pos"])
+        self._pos[lane] = residual["pos"]
         self._invalidate_lane_flush(lane)
         self._lane_pages[lane] = residual.get("pages", -1)
         self._lane_full[lane] = residual.get("full", False)
@@ -739,9 +774,9 @@ class ServeEngine:
         entry = self._paged_entry()
         if entry is None or segment < 0:
             return 0
-        plen = pull(entry["page_len"], "ring_view")[0, lane][None]  # (1, S)
-        cur = pull(entry["cur_slot"], "ring_view")[0, lane][None]   # (1,)
-        pos = np.asarray([residual["pos"]])
+        pos = self._pos[lane:lane + 1]
+        plen, cur = self._ring_geometry(pos, self.scfg.hot_slots,
+                                        self.scfg.page_t)     # (1, S), (1,)
         local = self._ring_page_ids(plen, cur, pos, self.scfg.page_t)[0]
         slots = np.flatnonzero(local >= 0)
         if slots.size == 0:
@@ -857,6 +892,7 @@ class ServeEngine:
         dec.install_pages(self.cache, lane, sel % S, rows,
                           dk=self._kv_split_width(), page_t=T,
                           new_pos=new_pos)
+        self._pos[lane] = new_pos
         self._lane_pages[lane, locals_] = gids
         cur = (new_pos // T) % S
         for j, g in zip(sel % S, gsel):
@@ -876,7 +912,7 @@ class ServeEngine:
         if self.reuse is None:
             return 0
         toks = np.asarray(tokens).ravel()
-        pos = int(pull(self.cache["pos"], "lane_state")[lane])
+        pos = int(self._pos[lane])
         n_pages = min(toks.size, pos) // self.scfg.page_t
         if n_pages <= 0 or self._lane_segments[lane] < 0:
             return 0
@@ -893,22 +929,25 @@ class ServeEngine:
                                     np.asarray(dst, np.int32))
         return len(new)
 
-    def _count_shared_mass(self, mass: np.ndarray, gids: np.ndarray) -> None:
-        """Accumulate the observation mass landing on shared pool pages vs
-        all resident pages — the shared-page mass share (BENCH kv_reuse)."""
+    def _count_shared_mass(self, mass: jax.Array, gids: np.ndarray) -> None:
+        """Accumulate the flat observation mass landing on shared pool pages
+        vs all resident pages — the shared-page mass share (BENCH kv_reuse).
+        The sums stay on the device; :meth:`reuse_stats` reads them."""
         if self.reuse is None:
             return
-        m = np.asarray(mass, np.float64)
-        self.reuse_mass["total"] += float(m[gids >= 0].sum())
-        self.reuse_mass["shared"] += float(m[gids >= self.reuse.base_gid].sum())
+        total, shared = _mass_split(
+            mass, jnp.asarray(gids.reshape(-1), jnp.int32),
+            self.reuse.base_gid)
+        self.reuse_mass["total"] = self.reuse_mass["total"] + total
+        self.reuse_mass["shared"] = self.reuse_mass["shared"] + shared
 
     def reuse_stats(self) -> dict | None:
         """Content-addressed store telemetry + the shared-page mass share."""
         if self.reuse is None:
             return None
         row = self.reuse.stats()
-        total = self.reuse_mass["total"]
-        row["shared_mass_share"] = (self.reuse_mass["shared"] / total
+        total = float(self.reuse_mass["total"])
+        row["shared_mass_share"] = (float(self.reuse_mass["shared"]) / total
                                     if total > 0 else 0.0)
         return row
 
@@ -941,6 +980,7 @@ class ServeEngine:
                 logits, self.cache, streams = out
             else:
                 (logits, self.cache), streams = out, {}
+            self._pos += 1
             self._set_kv_mass(streams)
             self._observe(tok, streams)
             self._maybe_tick()
@@ -985,16 +1025,41 @@ class ServeEngine:
 
     def _ring_view(self) -> tuple[np.ndarray, np.ndarray, np.ndarray] | None:
         """Host view of the paged ring: (page_len (B, S), cur_slot (B,),
-        pos (B,)).  Group 0 is representative — all groups advance in
-        lockstep, one appended token per step."""
-        entry = self._paged_entry()
-        if entry is None:
+        pos (B,)) int32, equal to the device's group-0 ``page_len``,
+        ``cur_slot`` and ``pos`` — but derived from the host-held positions
+        (``self._pos``), never read back from the device.
+
+        The ring's geometry is a pure function of a row's position: every
+        paged entry and layer group appends one token per step by the rule
+        of ``models/decode.py`` ``_append_attend_local`` (lines 440-449;
+        ``_append_attend_sharded`` follows it), ``merge_cache`` (161-184)
+        freezes an inactive lane or padded chunk step whole, ``pos``
+        included, and ``install_pages`` (697-722) lays a lane out as
+        streaming to ``new_pos`` would.  See :meth:`_ring_geometry`."""
+        if self._paged_entry() is None:
             return None
-        plen = pull(entry["page_len"], "ring_view")[0]       # (B, S)
-        cur = pull(entry["cur_slot"], "ring_view")[0]        # (B,)
-        pos = np.broadcast_to(pull(self.cache["pos"], "ring_view"),
-                              cur.shape)
+        pos = self._pos.copy()
+        plen, cur = self._ring_geometry(pos, self.scfg.hot_slots,
+                                        self.scfg.page_t)
         return plen, cur, pos
+
+    @staticmethod
+    def _ring_geometry(pos: np.ndarray, n_slots: int, page_t: int
+                       ) -> tuple[np.ndarray, np.ndarray]:
+        """(page_len (B, S), cur_slot (B,)) int32 of rows standing at
+        ``pos``, with T = page_t and S = n_slots: ``cur_slot = (p // T) %
+        S`` holds ``p % T`` tokens, and the slot k behind it, ``(cur_slot -
+        k) % S`` for k = 1..S-1, holds T once page ``p // T - k`` exists
+        (``p // T - k >= 0``), else 0.  A page that fills moves
+        ``cur_slot`` on at once and empties the next slot, so a row on a
+        page boundary holds 0 tokens at ``cur_slot``."""
+        pos = np.asarray(pos, np.int64)
+        page = pos // page_t
+        cur = page % n_slots
+        back = (cur[:, None] - np.arange(n_slots)[None]) % n_slots
+        plen = np.where(back == 0, (pos % page_t)[:, None],
+                        np.where(page[:, None] >= back, page_t, 0))
+        return plen.astype(np.int32), cur.astype(np.int32)
 
     @staticmethod
     def _ring_page_ids(plen: np.ndarray, cur: np.ndarray, pos: np.ndarray,
@@ -1009,6 +1074,16 @@ class ServeEngine:
         ids = cur_page[:, None] - (cur[:, None] - slots) % n_slots
         return np.where((plen > 0) & (ids >= 0), ids, -1)
 
+    def _ring_row0(self) -> tuple[np.ndarray, np.ndarray] | None:
+        """Batch row 0's (page_len (S,), logical page ids (S,)) on the host,
+        from the position-derived :meth:`_ring_view`."""
+        view = self._ring_view()
+        if view is None:
+            return None
+        plen, cur, pos = view
+        return plen[0], self._ring_page_ids(plen, cur, pos,
+                                            self.scfg.page_t)[0]
+
     def _kv_page_stream(self) -> tuple[jax.Array, jax.Array]:
         """Resident paged-KV window as (per-page fill, logical page ids).
 
@@ -1016,19 +1091,24 @@ class ServeEngine:
         and the change-tracking key for the slow-store flush); with the
         default kernel source the observer overrides it with the decode
         kernel's true per-page softmax mass (DESIGN.md §10).  Batch row 0
-        is representative: all rows advance in lockstep."""
-        view = self._ring_view()
-        if view is None:
+        is representative: all rows advance in lockstep.  Both come from
+        the host-held lockstep position (:meth:`_ring_view`: ``cur_slot =
+        (p // T) % S``, ``page_len`` T behind it while the page exists),
+        with no read from the device."""
+        row = self._ring_row0()
+        if row is None:
             return jnp.zeros((0,), jnp.float32), jnp.zeros((0,), jnp.int32)
-        plen, cur, pos = view
-        ids = self._ring_page_ids(plen, cur, pos, self.scfg.page_t)[0]
-        return jnp.asarray(plen[0], jnp.float32), jnp.asarray(ids, jnp.int32)
+        fill, ids = row
+        return jnp.asarray(fill, jnp.float32), jnp.asarray(ids, jnp.int32)
 
     def _kv_lane_stream(self, active: np.ndarray | None = None,
                         ) -> tuple[np.ndarray, np.ndarray] | None:
         """Lane mode: (mass (L, S), global page ids (L, S)) — each lane's
         resident ring pages mapped into its slow-store segment's address
-        space; lanes outside ``active`` (default: the live mask) are -1."""
+        space; lanes outside ``active`` (default: the live mask) are -1.
+        Host arrays built from the host-held lane positions
+        (:meth:`_ring_view`: ``cur_slot = (p // T) % S``, ``page_len`` T
+        behind it while the page exists), with no read from the device."""
         view = self._ring_view()
         if view is None:
             return None
@@ -1070,11 +1150,7 @@ class ServeEngine:
             entry = self._paged_entry()
             if entry is None:
                 return
-            mass, ids = self._kv_page_stream()
-            if not ids.size:
-                return
-            ids = pull(ids, "ring_view")
-            fill = np.asarray(pull(mass, "ring_view"), np.int64)  # page_len
+            fill, ids = self._ring_row0()                 # page_len, ids
             changed = np.array([
                 self._kv_flushed.get((0, slot))
                 != (int(ids[slot]), int(fill[slot]))

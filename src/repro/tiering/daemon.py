@@ -189,6 +189,18 @@ class ResourceHandle:
         return row
 
 
+def _placed_together(state: TieredMemoryState) -> TieredMemoryState:
+    """``state`` with its uncommitted arrays committed where its first
+    committed array lives; unchanged when none is committed."""
+    arrays = [x for x in jax.tree.leaves(state) if isinstance(x, jax.Array)]
+    home = next((x.sharding for x in arrays if x.committed), None)
+    if home is None:
+        return state
+    return jax.tree.map(
+        lambda x: jax.device_put(x, home)
+        if isinstance(x, jax.Array) and not x.committed else x, state)
+
+
 class NeoMemDaemon:
     """One daemon loop multiplexed across every registered tiered resource."""
 
@@ -240,9 +252,17 @@ class NeoMemDaemon:
         return sum(h.mem.quota for h in self.resources.values())
 
     def tick(self) -> dict[str, MigrationEvent]:
-        """One daemon tick: run whatever cadences are due, for ALL resources."""
+        """One daemon tick: run whatever cadences are due, for ALL resources.
+
+        The leaves a cadence rebuilds on the host go back where the rest of
+        the resource's state lives: jitted tier programs key on which inputs
+        are committed to a device, so a state that changed its mix from
+        tick to tick would compile them anew."""
         with span("tier/tick"):
-            return self._run_cadences()
+            events = self._run_cadences()
+            for h in self.resources.values():
+                h.state = _placed_together(h.state)
+            return events
 
     def _run_cadences(self) -> dict[str, MigrationEvent]:
         self._tick += 1
