@@ -1,29 +1,20 @@
-"""Model side of the benchmark: configuration files, seeded weights, and the
-plain float32 reference that decides ``correct``.
+"""What every architecture of the benchmark shares: configuration files,
+the weight key from a seed, the ring's attention window, the float32
+matmul and norm helpers of the references, and the comparison of served
+tokens with a reference.
 
-Nothing here imports the program except its ``ArchConfig`` schema, which
-the engine under test is built from.  The weights are the benchmark's own
-(drawn on the device from the seed, in the parameter layout the engine
-reads), and the reference is a straightforward decoder forward pass
-written from the published description, with the departures the
-configuration file lists.
+What depends on the architecture (engine fields, weights, the reference's
+layers, work counts) lives in one module per published ``model_type``,
+``bench/arch/<model_type>.py``, which may import the helpers here.
 """
 from __future__ import annotations
 
-import functools
 import json
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-# Published keys of a dense GQA decoder config.json that the engine's
-# ArchConfig takes, as (published key, ArchConfig field).
-_WIDTHS = (("num_hidden_layers", "n_layers"), ("hidden_size", "d_model"),
-           ("num_attention_heads", "n_heads"),
-           ("num_key_value_heads", "n_kv_heads"),
-           ("intermediate_size", "d_ff"), ("vocab_size", "vocab"))
 
 
 def load_config(path: Path) -> dict:
@@ -37,74 +28,6 @@ def load_config(path: Path) -> dict:
     return conf
 
 
-def arch_fields(conf: dict) -> dict:
-    """ArchConfig keyword arguments for a configuration as run.
-
-    Raises for a published setting the engine cannot run (the file must
-    then list the departure under ``assumed`` with the value as run)."""
-    if conf.get("hidden_act", "silu") != "silu":
-        raise ValueError(f"hidden_act {conf['hidden_act']!r}: only silu "
-                         f"(SwiGLU) is served")
-    if conf.get("partial_rotary_factor", 1.0) != 1.0:
-        raise ValueError("partial rotary embeddings are not served")
-    if not conf.get("tie_word_embeddings", True):
-        raise ValueError("untied output embeddings are not served")
-    fields = {field: int(conf[key]) for key, field in _WIDTHS}
-    fields["head_dim"] = int(conf.get("head_dim") or
-                             conf["hidden_size"] // conf["num_attention_heads"])
-    fields["norm"] = "ln" if "layer_norm_eps" in conf else "rms"
-    fields["qkv_bias"] = bool(conf.get("use_qkv_bias",
-                                       conf.get("model_type") == "qwen2"))
-    fields["rope_theta"] = float(conf["rope_theta"])
-    return dict(fields, name=conf["name"], family="dense", mlp="swiglu",
-                tie_embeddings=True)
-
-
-def norm_eps(conf: dict) -> float:
-    return float(conf.get("layer_norm_eps", conf.get("rms_norm_eps", 1e-6)))
-
-
-# ---------------------------------------------------------------------------
-# weights: drawn on the device from the seed, bf16 as served
-# ---------------------------------------------------------------------------
-
-def make_weights(f: dict, key: jax.Array):
-    """bf16 weights of a dense decoder in the engine's parameter layout
-    (group-stacked blocks), drawn in ONE jitted call.  Norm scales and
-    biases are drawn too (not ones and zeros), so the reference checks the
-    paths that read them."""
-    @jax.jit
-    def draw(key):
-        d, f_, v = f["d_model"], f["d_ff"], f["vocab"]
-        h, hkv, dh, n = f["n_heads"], f["n_kv_heads"], f["head_dim"], \
-            f["n_layers"]
-        ks = (jax.random.fold_in(key, i) for i in range(1 << 20))
-
-        def w(shape, fan_in, dtype=jnp.bfloat16):
-            return (jax.random.normal(next(ks), shape, jnp.float32)
-                    * fan_in ** -0.5).astype(dtype)
-
-        def norm(shape):
-            p = {"scale": 1.0 + 0.1 * jax.random.normal(next(ks), shape)}
-            if f["norm"] == "ln":
-                p["bias"] = 0.1 * jax.random.normal(next(ks), shape)
-            return p
-
-        attn = {"wq": w((n, d, h * dh), d), "wk": w((n, d, hkv * dh), d),
-                "wv": w((n, d, hkv * dh), d), "wo": w((n, h * dh, d), h * dh)}
-        if f["qkv_bias"]:
-            for b, width in (("bq", h * dh), ("bk", hkv * dh),
-                             ("bv", hkv * dh)):
-                attn[b] = w((n, width), 100.0)     # rms 0.1
-        block = {"ln1": norm((n, d)), "ln2": norm((n, d)), "attn": attn,
-                 "ffn": {"w_in": w((n, d, f_), d), "w_gate": w((n, d, f_), d),
-                         "w_out": w((n, f_, d), f_)}}
-        return {"embed": {"table": w((v, d), d)}, "blocks": [block],
-                "final_norm": norm((d,))}
-
-    return draw(key)
-
-
 def weight_key(seed: int) -> jax.Array:
     """A JAX key from any whole-number seed (wider than 32 bits too)."""
     word = np.random.SeedSequence([seed, 1]).generate_state(1)[0]
@@ -112,7 +35,7 @@ def weight_key(seed: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# the plain reference
+# what the references share
 # ---------------------------------------------------------------------------
 
 def window_mask(n: int, page_t: int, ring_pages: int) -> np.ndarray:
@@ -171,72 +94,11 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("f", "eps", "fmt"))
-def _layer(x, p, mask, *, f, eps, fmt):
-    """One decoder layer on (S, d) float32 activations."""
-    h, hkv, dh = f["n_heads"], f["n_kv_heads"], f["head_dim"]
-    s = x.shape[0]
-    a = _norm(p["ln1"], x, f["norm"], eps)
-    q = _mm(a, p["attn"]["wq"], fmt) + p["attn"].get("bq", 0.0)
-    k = _mm(a, p["attn"]["wk"], fmt) + p["attn"].get("bk", 0.0)
-    v = _mm(a, p["attn"]["wv"], fmt) + p["attn"].get("bv", 0.0)
-    q = _rope(q.reshape(s, h, dh), f["rope_theta"])
-    k = _rope(k.reshape(s, hkv, dh), f["rope_theta"])
-    v = v.reshape(s, hkv, dh)
-    rep = h // hkv
-    k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-    sc = jnp.einsum("qhd,khd->hqk", _round(q, fmt), _round(k, fmt),
-                    precision=jax.lax.Precision.HIGHEST) * dh ** -0.5
-    sc = jnp.where(mask[None], sc, -jnp.inf)
-    pr = jax.nn.softmax(sc, axis=-1)
-    o = jnp.einsum("hqk,khd->qhd", _round(pr, fmt), _round(v, fmt),
-                   precision=jax.lax.Precision.HIGHEST).reshape(s, h * dh)
-    x = x + _mm(o, p["attn"]["wo"], fmt)
-    b = _norm(p["ln2"], x, f["norm"], eps)
-    g = _mm(b, p["ffn"]["w_gate"], fmt)
-    u = _mm(b, p["ffn"]["w_in"], fmt)
-    return x + _mm(jax.nn.silu(g) * u, p["ffn"]["w_out"], fmt)
-
-
-@functools.partial(jax.jit, static_argnames=("f", "eps", "fmt"))
-def _head(x, fin, table, *, f, eps, fmt):
-    """(S, d) final activations -> (S, V) float32 logits (tied head)."""
-    return _mm(_norm(fin, x, f["norm"], eps), table.T, fmt)
-
-
-def forward_logits(f: dict, eps: float, params, tokens: np.ndarray,
-                   page_t: int, ring_pages: int, n: int, fmt: str = "f32"):
-    """Logits (S, V) of the reference over ``tokens`` (S,), layer by layer
-    so that only one layer's weights are upcast at a time.  Every sequence
-    is padded to ``n`` (the configuration's max_seq; padding sits after
-    the tokens, which a causal mask never lets them see), so a run
-    compiles one shape."""
-    s = len(tokens)
-    toks = np.zeros(n, np.int32)
-    toks[:s] = tokens
-    mask = jnp.asarray(window_mask(n, page_t, ring_pages))
-    x = params["embed"]["table"][jnp.asarray(toks)].astype(jnp.float32)
-    blocks = params["blocks"][0]
-    frozen = _Frozen(f)
-    for layer in range(f["n_layers"]):
-        p = jax.tree.map(lambda a: a[layer], blocks)
-        x = _layer(x, p, mask, f=frozen, eps=eps, fmt=fmt)
-    return _head(x, params["final_norm"], params["embed"]["table"],
-                 f=frozen, eps=eps, fmt=fmt)[:s]
-
-
-class _Frozen(dict):
-    """A hashable dict, so the architecture fields can be a static jit
-    argument."""
-
-    def __hash__(self):
-        return hash(tuple(sorted(self.items())))
-
-
-def served_gaps(f: dict, eps: float, params, prompt: np.ndarray,
+def served_gaps(arch, f: dict, eps: float, params, prompt: np.ndarray,
                 out: list[int], page_t: int, ring_pages: int, n: int,
                 control: bool = False) -> dict:
-    """Compare one served request with the reference.
+    """Compare one served request with the reference of ``arch``, the
+    configuration's architecture module.
 
     The k-th output token was chosen from the logits at position
     ``len(prompt) - 1 + k``.  Returns the widest gap by which a served
@@ -246,11 +108,12 @@ def served_gaps(f: dict, eps: float, params, prompt: np.ndarray,
     out = np.asarray(out, np.int32)
     seq = np.concatenate([np.asarray(prompt, np.int32), out[:-1]])
     first = len(prompt) - 1
-    ref = forward_logits(f, eps, params, seq, page_t, ring_pages, n)[first:]
+    ref = arch.forward_logits(f, eps, params, seq, page_t, ring_pages,
+                              n)[first:]
     res = {"gap": float(_gap(ref, jnp.asarray(out))), "tokens": int(out.size)}
     if control:
-        low = forward_logits(f, eps, params, seq, page_t, ring_pages, n,
-                             fmt="fp8")[first:]
+        low = arch.forward_logits(f, eps, params, seq, page_t, ring_pages,
+                                  n, fmt="fp8")[first:]
         res["control_gap"] = float(_gap(ref, jnp.argmax(low, -1)))
     return res
 
@@ -260,3 +123,25 @@ def _gap(ref, chosen):
     best = jnp.max(ref, axis=-1)
     got = jnp.take_along_axis(ref, chosen[:, None], axis=-1)[:, 0]
     return jnp.max(best - got)
+
+
+# ---------------------------------------------------------------------------
+# names kept for callers outside the benchmark's files
+# ---------------------------------------------------------------------------
+
+def arch_fields(conf: dict) -> dict:
+    """``fields`` of the dense decoder's module, ``bench/arch/qwen2.py``.
+    Kept for ``tests/test_ring_view.py``, which builds its tiny engine with
+    it; the harness finds a configuration's module by ``model_type``
+    (``bench.spec.arch_module``)."""
+    return _dense().fields(conf)
+
+
+def make_weights(f: dict, key: jax.Array):
+    """``make_weights`` of ``bench/arch/qwen2.py`` (see ``arch_fields``)."""
+    return _dense().make_weights(f, key)
+
+
+def _dense():
+    from bench.arch import qwen2
+    return qwen2

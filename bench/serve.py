@@ -2,11 +2,15 @@
 measure a window of whole scheduler steps, and check what it served.
 
 The system under test is ``Scheduler.step()`` over a ``ServeEngine`` in
-lane mode with the paged ring, the async migration plane, the ``kv`` and
-``embeddings`` tiers (slow stores in pinned host memory), kernel-exported
-KV mass and the ``none`` slow codec.  The harness records its own spans
-around the calls into each layer (``sched.step``, ``advance_lanes``,
-``prefill_lane``, ``daemon.tick``) from an engine subclass.
+lane mode with the paged ring, the async migration plane, the ``kv`` tier
+and the tiers the configuration's ``serve`` block names (``embeddings``
+unless it says otherwise; slow stores in pinned host memory),
+kernel-exported KV mass and the ``none`` slow codec.  The harness records
+its own spans around the calls into each layer (``sched.step``,
+``advance_lanes``, ``prefill_lane``, ``daemon.tick``) from an engine
+subclass.  What depends on the architecture (engine fields, weights, the
+reference, work counts) comes from the configuration's module
+``bench/arch/<model_type>.py``.
 """
 from __future__ import annotations
 
@@ -21,7 +25,7 @@ import jax
 import numpy as np
 
 from bench import model, trace as tr_lib, work
-from bench.spec import Cell, reader
+from bench.spec import Cell, arch_module, reader
 from bench.traffic import ClosedLoop, make_requests
 from repro.configs.base import ArchConfig
 from repro.serve.engine import ServeConfig, ServeEngine
@@ -91,16 +95,23 @@ class SpannedEngine(ServeEngine):
 
 
 def serve_config(geo: dict) -> ServeConfig:
+    """The engine's settings from a configuration's ``serve`` block.  Its
+    optional keys: ``resources``, the tiered resources besides the KV ring
+    (default the embedding table), and ``expert_hot_slots`` and
+    ``expert_quota`` for an ``experts`` tier (default ServeConfig's)."""
+    experts = {k: geo[k] for k in ("expert_hot_slots", "expert_quota")
+               if k in geo}
     return ServeConfig(
         max_seq=geo["max_seq"], page_t=geo["page_t"],
         hot_slots=geo["ring_pages"], paged=True,
         migration_interval=geo["migration_interval"],
-        resources=("embeddings",), kv_quota=geo["kv_quota"],
+        resources=tuple(geo.get("resources", ("embeddings",))),
+        kv_quota=geo["kv_quota"],
         embed_hot_slots=geo["embed_hot_pages"],
         embed_quota=geo["embed_quota"],
         embed_rows_per_page=geo["embed_rows_per_page"], lanes=geo["lanes"],
         kv_segments=geo["kv_segments"], kv_mass_source="kernel",
-        slow_codec="none", async_migration=True)
+        slow_codec="none", async_migration=True, **experts)
 
 
 class Loop:
@@ -144,7 +155,8 @@ class Context:
     """What a metric reader may read (bench/metrics/<name>.py)."""
 
     cell: Cell
-    f: dict                    # ArchConfig fields
+    arch: object               # bench/arch/<model_type>.py of the config
+    f: dict                    # ArchConfig fields (arch.fields)
     geo: dict                  # serving geometry
     peaks: dict
     t0: float                  # window start and end, host clock
@@ -225,10 +237,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     ``correct`` and ``compared`` are its verdict and readings; the
     program's own are under ``program``."""
     conf, geo, traffic = cell.conf, cell.conf["serve"], cell.traffic
-    f = model.arch_fields(conf)
-    eps = model.norm_eps(conf)
+    arch = arch_module(cell)
+    f = arch.fields(conf)
+    eps = arch.norm_eps(conf)
     peaks = work.device_peaks(device_kind)
-    params = model.make_weights(f, model.weight_key(seed))
+    params = arch.make_weights(f, model.weight_key(seed))
     jax.block_until_ready(params)
     reqs = make_requests(traffic, f["vocab"], seed)
     eng = SpannedEngine(ArchConfig(**f), params, serve_config(geo))
@@ -281,7 +294,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     peak = int(stats.get("peak_bytes_in_use", 0))
 
     ctx = Context(
-        cell=cell, f=f, geo=geo, peaks=peaks, t0=t0, t1=t1,
+        cell=cell, arch=arch, f=f, geo=geo, peaks=peaks, t0=t0, t1=t1,
         steps=loop.steps[first_step:], requests=list(loop.all),
         spans=[s for s in eng.spans if t0 <= s[1] < t1],
         bodies=[b for b in eng.bodies if t0 <= b[0] < t1],
@@ -310,7 +323,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     del eng, sched, loop
     gc.collect()
     t_check = time.perf_counter()
-    check = check_served(f, eps, params, geo, served, control=control)
+    check = check_served(arch, f, eps, params, geo, served, control=control)
     log(f"check: {len(served)} requests, {check['tokens']} served tokens "
         f"against the reference in {time.perf_counter() - t_check:.1f} s")
     limit = float(cell.limits["max_logit_gap"])
@@ -400,15 +413,15 @@ def _sample(loop: Loop, t0: float, t1: float, seed: int, n: int,
     return [(r.prompt.copy(), list(r.out)) for r in pick]
 
 
-def check_served(f: dict, eps: float, params, geo: dict, served,
+def check_served(arch, f: dict, eps: float, params, geo: dict, served,
                  control: bool = False) -> dict:
-    """The widest gap of any served token below the reference's best, over
-    the sampled requests (see bench/model.served_gaps)."""
+    """The widest gap of any served token below the best of ``arch``'s
+    reference, over the sampled requests (see bench/model.served_gaps)."""
     gap, ctl, tokens = 0.0, 0.0, 0
     for prompt, out in served:
-        res = model.served_gaps(f, eps, params, prompt, out, geo["page_t"],
-                                geo["ring_pages"], geo["max_seq"],
-                                control=control)
+        res = model.served_gaps(arch, f, eps, params, prompt, out,
+                                geo["page_t"], geo["ring_pages"],
+                                geo["max_seq"], control=control)
         gap = max(gap, res["gap"])
         ctl = max(ctl, res.get("control_gap", 0.0))
         tokens += res["tokens"]

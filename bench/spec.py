@@ -1,12 +1,14 @@
 """Find a cell's pieces by name: ``BENCHMARK.json`` at the root, and under
-``bench/`` the configuration file, ``traffic/<traffic>.json``,
-``limits/<cell>.json`` and one reader ``metrics/<metric>.py`` per metric.
-A cell is added by adding such files; nothing here names a cell."""
+``bench/`` the configuration file, its architecture module
+``arch/<model_type>.py``, ``traffic/<traffic>.json``, ``limits/<cell>.json``
+and one reader ``metrics/<metric>.py`` per metric.  A cell, a metric or an
+architecture is added by adding such files; nothing here names one."""
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 import json
+import re
 from pathlib import Path
 
 from bench.model import load_config
@@ -56,11 +58,32 @@ def load_cell(name: str, root: Path = REPO) -> Cell:
         end_to_end=e2e, per_layer=per_layer, root=root)
 
 
-def reader(cell: Cell, metric: str):
-    """The reader module of ``metric``: ``bench/metrics/<metric>.py``."""
-    path = cell.root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def reader(cell: Cell, metric: str):
+    """The reader module of ``metric``: ``bench/metrics/<metric>.py``."""
+    return _load(cell.root / "bench" / "metrics" / f"{metric}.py",
+                 f"bench_metric_{metric.replace('.', '_')}")
+
+
+def arch_module(cell: Cell):
+    """The architecture module of the cell's configuration:
+    ``bench/arch/<model_type>.py`` under the cell's root, named by the
+    file's published ``model_type``.  There is no fallback: a
+    ``model_type`` without a module is an error that names the path."""
+    kind = cell.conf.get("model_type")
+    if not isinstance(kind, str) or not re.fullmatch(r"[A-Za-z0-9_]+", kind):
+        raise ValueError(f"configuration {cell.conf.get('name')!r}: "
+                         f"model_type {kind!r} names no architecture module")
+    rel = f"bench/arch/{kind}.py"
+    path = cell.root / rel
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"configuration {cell.conf.get('name')!r}: no architecture "
+            f"module {rel} for model_type {kind!r} (looked for {path})")
+    return _load(path, f"bench_arch_{kind}")

@@ -24,9 +24,10 @@ rank is that of ``frac(a + i / phi)`` (prompts) or ``frac(b + i * (sqrt 2
 - 1))`` (outputs) among the pool's, where only the offsets a and b come
 from the seed: these sequences spread evenly over (0, 1), so any run of
 consecutive requests -- the ones a window serves -- holds nearly the same
-mix of lengths whatever the seed.  Token ids are
-Zipf-ranked, with the ranks mapped to ids by a permutation drawn from the
-seed, so the hot embedding rows are spread over the vocabulary.
+mix of lengths whatever the seed.  The pool's prompt tokens are the
+same for every seed too, in another order: Zipf ranks at the quantiles of
+their count, mapped to ids by one fixed permutation (so the hot embedding
+rows are spread over the vocabulary), and shuffled by the seed.
 """
 from __future__ import annotations
 
@@ -84,6 +85,17 @@ def zipf_cdf(vocab: int, a: float) -> np.ndarray:
     return np.cumsum(p / p.sum())
 
 
+def zipf_tokens(vocab: int, a: float, n: int) -> np.ndarray:
+    """The ``n`` prompt tokens of a pool: Zipf ranks at the n quantiles
+    ``(k + 0.5) / n``, mapped to ids by one fixed permutation of the
+    vocabulary.  The same tokens for every seed, so the hot embedding rows
+    (and the pages that hold them) are the same too."""
+    ranks = np.searchsorted(zipf_cdf(vocab, a), (np.arange(n) + 0.5) / n,
+                            side="right")
+    ids = _rng(0, 3).permutation(vocab).astype(np.int32)
+    return ids[np.minimum(ranks, vocab - 1)]
+
+
 def make_requests(traffic: dict, vocab: int, seed: int) -> list[Req]:
     """The loop's requests, in the order the clients take them."""
     n = int(traffic["pool"])
@@ -93,12 +105,8 @@ def make_requests(traffic: dict, vocab: int, seed: int) -> list[Req]:
                          _strata((a + i * _STRIDE_PROMPT) % 1.0))
     outputs = lengths_at(traffic["output"],
                          _strata((b + i * _STRIDE_OUTPUT) % 1.0))
-    content = _rng(seed, 3)
-    ids = content.permutation(vocab).astype(np.int32)
-    cdf = zipf_cdf(vocab, float(traffic["zipf_a"]))
-    ranks = np.searchsorted(cdf, content.random(int(prompts.sum())),
-                            side="right")
-    toks = ids[np.minimum(ranks, vocab - 1)]
+    toks = _rng(seed, 3).permutation(
+        zipf_tokens(vocab, float(traffic["zipf_a"]), int(prompts.sum())))
     cuts = np.cumsum(prompts)[:-1]
     return [Req(p, int(m)) for p, m in zip(np.split(toks, cuts), outputs)]
 

@@ -2,8 +2,10 @@
 
 These functions are the yardstick for ``mfu`` and ``paged_attn_roofline``:
 what the algorithm needs for the tokens the window processed, whatever
-implements it.  ``f`` is the ArchConfig field dict of the configuration
-(:func:`bench.model.arch_fields`).
+implements it.  The counts that depend on the architecture come from its
+module, ``arch`` (``bench/arch/<model_type>.py``: ``token_flops``,
+``paged_attn_call``, ``attn_layers``); ``f`` is the ArchConfig field dict
+it made from the configuration (its ``fields``).
 """
 from __future__ import annotations
 
@@ -32,47 +34,29 @@ def attended(pos, page_t: int, ring_pages: int) -> np.ndarray:
     return pos + 1 - oldest
 
 
-def matmul_params(f: dict) -> int:
-    """Weights one token multiplies through: the layers' projections and
-    MLP, and the tied output head."""
-    d, h, hkv, dh = f["d_model"], f["n_heads"], f["n_kv_heads"], f["head_dim"]
-    per_layer = d * (h + 2 * hkv) * dh + h * dh * d + 3 * d * f["d_ff"]
-    return f["n_layers"] * per_layer + f["vocab"] * d
+def model_flops(arch, f: dict, positions, page_t: int,
+                ring_pages: int) -> float:
+    """Forward FLOPs of the tokens processed at ``positions``, each
+    attending the ring's keys."""
+    keys = attended(np.asarray(positions), page_t, ring_pages)
+    return float(np.sum(arch.token_flops(f, keys)))
 
 
-def token_flops(f: dict, keys) -> np.ndarray:
-    """Forward FLOPs of tokens that attend ``keys`` positions each."""
-    attn = 4 * f["n_heads"] * f["head_dim"] * f["n_layers"]
-    return 2.0 * matmul_params(f) + attn * np.asarray(keys, np.float64)
-
-
-def paged_attn_call(f: dict, keys) -> tuple[float, float]:
-    """(FLOPs, bytes) of one ``paged_attn`` call of one layer: one query
-    token per active lane, ``keys`` the keys each of those lanes attends.
-    Bytes: bf16 K and V of the attended tokens, the f32 query, and the f32
-    output (numerator, running max and denominator)."""
-    keys = np.asarray(keys, np.float64)
-    h, hkv, dh = f["n_heads"], f["n_kv_heads"], f["head_dim"]
-    flops = 4.0 * h * dh * keys.sum()
-    kv = 2 * 2 * hkv * dh * keys.sum()
-    q_out = keys.size * (4 * h * dh + 4 * (h * dh + 2 * h))
-    return flops, kv + q_out
-
-
-def paged_attn_least_s(f: dict, bodies, peaks: dict, page_t: int,
+def paged_attn_least_s(arch, f: dict, bodies, peaks: dict, page_t: int,
                        ring_pages: int) -> tuple[float, str]:
     """Least device time for the kernel's work over ``bodies`` (each the
-    positions of the active lanes of one decode body), at the device's
-    peaks, and which bound sets it."""
+    positions of the active lanes of one decode body) in every layer that
+    calls it, at the device's peaks, and which bound sets it."""
     t_flops = t_bytes = 0.0
     least = 0.0
     for positions in bodies:
         if not len(positions):
             continue
-        fl, by = paged_attn_call(f, attended(positions, page_t, ring_pages))
+        fl, by = arch.paged_attn_call(f, attended(positions, page_t,
+                                                  ring_pages))
         a, b = fl / peaks["bf16_flops"], by / peaks["hbm_bytes_s"]
         t_flops += a
         t_bytes += b
         least += max(a, b)
-    return least * f["n_layers"], ("memory" if t_bytes >= t_flops
-                                   else "compute")
+    return least * arch.attn_layers(f), ("memory" if t_bytes >= t_flops
+                                         else "compute")

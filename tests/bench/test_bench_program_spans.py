@@ -11,7 +11,7 @@ import pytest
 
 import bench_cells
 from bench import model, program_spans, serve, trace
-from bench.spec import reader
+from bench.spec import arch_module, reader
 from repro import spans
 from repro.configs.base import ArchConfig
 from repro.serve.engine import ServeEngine
@@ -48,18 +48,18 @@ def test_program_annotations_leave_the_breakdown_names(traced):
 # -- the pull count, by hand ------------------------------------------------
 
 # Pulls of one scheduler step with no daemon tick, every lane decoding or
-# streaming its prompt: advance_lanes reads the ring view (page_len,
-# cur_slot, pos) for the KV stream, the kernel's KV mass and the logits; the
-# scheduler's tenant meter reads the ring view again and the lookup's hit
-# mask.
-PULLS_PER_STEP = {"ring_view": 6, "kv_mass": 1, "logits": 1, "meter_hit": 1}
+# streaming its prompt: advance_lanes reads the logits (the KV ring's
+# geometry is known on the host and the KV mass stays on the device); the
+# scheduler's tenant meter reads the lookup's hit mask.
+PULLS_PER_STEP = {"logits": 1, "meter_hit": 1}
 
 
 def test_pulls_per_step_in_a_tick_free_window_match_the_hand_count():
     conf = dict(bench_cells.TINY_CONFIG, name="tiny")
     geo = dict(conf["serve"], migration_interval=10**6)
-    f = model.arch_fields(conf)
-    params = model.make_weights(f, model.weight_key(0))
+    arch = arch_module(SimpleNamespace(root=bench_cells.REPO, conf=conf))
+    f = arch.fields(conf)
+    params = arch.make_weights(f, model.weight_key(0))
     eng = ServeEngine(ArchConfig(**f), params, serve.serve_config(geo))
     sched = Scheduler(eng, [Tenant("t")],
                       SchedConfig(prefill_chunk=geo["prefill_chunk"]))

@@ -39,6 +39,18 @@ def test_every_seed_gets_the_same_mix_in_another_order():
         assert abs(a.mean() / b.mean() - 1) < 0.01
 
 
+def test_every_seed_gets_the_same_tokens_in_another_order():
+    # the hot embedding rows, and how often each is read, do not depend on
+    # the seed: only the order of the pool's prompt tokens does
+    for name in CELLS:
+        traffic, vocab = _cell(name)
+        runs = [np.concatenate([r.prompt for r in make_requests(traffic,
+                                                                vocab, s)])
+                for s in (5, 2**40 + 1)]
+        assert not np.array_equal(runs[0], runs[1])
+        assert np.array_equal(np.sort(runs[0]), np.sort(runs[1]))
+
+
 def test_lengths_stay_in_their_ranges_for_every_cell():
     for name in CELLS:
         traffic, vocab = _cell(name)
